@@ -8,8 +8,8 @@ Two halves, one artifact:
    with its measured vs predicted latency and absolute percentage
    error. ``--smoke`` (or ``CLUSTER_BENCH_SMOKE``) uses the synthetic
    mode (frozen row table + hidden roofline — deterministic, jax-free);
-   the full tier compiles and replays the real Pallas kernels when jax
-   is importable and falls back to synthetic when not. The ``mode``
+   the full tier compiles and replays the real Pallas kernels, and a
+   kernel that fails to compile or run fails the bench. The ``mode``
    field keys the gate cell, so synthetic and measured trajectories
    never cross-compare.
 2. **End-to-end $ delta** — the same small llm-FaaS scenario run twice
@@ -53,13 +53,7 @@ MODEL = "deepseek-7b"
 def _calibrate(smoke: bool) -> dict:
     if smoke:
         return calibrate(mode="synthetic", seed=0)
-    try:
-        import jax  # noqa: F401
-        return calibrate(mode="measure", repeats=5, small=True)
-    except Exception:
-        # No jax (or no functional backend) on this runner: the
-        # synthetic tier still exercises fit + consumers end to end.
-        return calibrate(mode="synthetic", seed=0)
+    return calibrate(mode="measure", repeats=5, small=True)
 
 
 def _op_rows(artifact: dict) -> list[dict]:

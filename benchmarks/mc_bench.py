@@ -28,8 +28,9 @@ fast-forwards dense regimes analytically. On parallel hardware
 shape wins; on a single-core CI runner XLA executes the batch
 serially and the pool baseline stays ahead. ``meta`` records
 ``cpu_count``, ``pool_workers``, the compile time, and the persistent
-compile-cache hit evidence (entry counts when
-``REPRO_MC_COMPILE_CACHE`` is set), so a number measured on one
+compile-cache hit evidence (entry counts of the cache directory:
+``JAX_COMPILATION_CACHE_DIR`` where set, else ``.jax_cache/``), so a
+number measured on one
 machine is never mistaken for a hardware-independent ratio; CI gates
 cells/sec run-over-run on same-``cpu_count`` runners (kind ``mc`` in
 ``benchmarks.regression_gate``) rather than against an absolute
@@ -123,28 +124,19 @@ def _jax_sweep(grid: list) -> tuple[list[dict], int, int]:
     """The sweep runner's jax route, inlined so the TIMED run also
     yields the kernel's iteration/event counters (``run_sweep`` rows
     drop ``mc_stats``). Returns (rows, total_iters, total_events)."""
-    from repro.mc.dispatch import supported, tasks_supported
-    from repro.mc.engine import run_scenarios
+    from repro.mc.engine import route, run_scenarios
 
     scs = [c.to_scenario() for c in grid]
-    prebuilt = []
-    for sc in scs:
-        why = supported(sc)
-        if why is None:
-            built = sc.workload.build()
-            why = tasks_supported(built[0])
-            prebuilt.append(built)
-        if why is not None:
-            raise RuntimeError(
-                f"bench cell outside the batched regime ({why}) — the "
-                "bench grid must ride the device end to end")
-    results = run_scenarios(scs, prebuilt=prebuilt)
+    reasons, built = route(scs)
+    refused = {why for why in reasons if why is not None}
+    if refused:
+        raise RuntimeError(
+            f"bench cells outside the batched regime ({sorted(refused)}) "
+            "— the bench grid must ride the device end to end")
+    results = run_scenarios(scs, prebuilt=[built[k] for k in range(len(scs))])
     rows, iters, events = [], 0, 0
     for cell, res in zip(grid, results):
-        row = asdict(cell)
-        row.update(res.summary())
-        row["backend"] = "jax"
-        rows.append(row)
+        rows.append({**asdict(cell), **res.summary()})
         iters += res.mc_stats["iters"]
         events += res.mc_stats["events"]
     return rows, iters, events
@@ -182,7 +174,7 @@ def bench_grid(spec: dict, median_of: int = 1) -> tuple[list[dict], dict]:
 
     step = max(1, n_cells // VERIFY_CELLS)
     for k in range(0, n_cells, step):
-        if _strip(jax_rows[k]) != pool_rows[k]:
+        if _strip(jax_rows[k]) != _strip(pool_rows[k]):
             raise RuntimeError(
                 f"bit-identity violated on bench cell {k}: "
                 f"{pool_rows[k]} != {jax_rows[k]}")
@@ -230,12 +222,11 @@ def bench_grid(spec: dict, median_of: int = 1) -> tuple[list[dict], dict]:
         "events_per_cell": events / n_cells,
         "events_per_iter": events / max(iters, 1),
         "iter_reduction_vs_one_event": events / max(iters, 1),
-        "compile_cache": (
-            None if cache_dir is None else
-            {"dir": cache_dir, "entries_before": cache_before,
-             "entries_after_cold": cache_after_cold,
-             # cold run hit the cache iff no new entries appeared
-             "cold_was_hit": cache_after_cold == cache_before}),
+        "compile_cache": {
+            "dir": cache_dir, "entries_before": cache_before,
+            "entries_after_cold": cache_after_cold,
+            # cold run hit the cache iff no new entries appeared
+            "cold_was_hit": cache_after_cold == cache_before},
         "cpu_count": cpus,
         "verified_cells": len(range(0, n_cells, step)),
     }

@@ -49,6 +49,6 @@ def main():
 
 
 if __name__ == "__main__":
-    # compare_serial forks a multiprocessing pool: spawn-start platforms
-    # (macOS, Windows) re-import this module in the children.
+    # compare_serial starts a spawn-context process pool, whose workers
+    # re-import this module.
     main()

@@ -179,52 +179,35 @@ def run_sweep(grid: list[Cell], *, parallel: bool = True,
     if not parallel or len(grid) <= 1:
         return [run_cell(c) for c in grid]
     processes = processes or min(len(grid), os.cpu_count() or 2)
-    with mp.Pool(processes) as pool:
+    # Spawn, never fork: the parent may already hold a JAX client (the
+    # jax route runs its device program first), and a forked copy of
+    # it is unsafe. Workers run the scalar engine, which never imports
+    # JAX, so they stay off the device.
+    with mp.get_context("spawn").Pool(processes) as pool:
         return pool.map(run_cell, grid)
 
 
 def _run_sweep_jax(grid: list[Cell], *, parallel: bool,
                    processes: Optional[int]) -> list[dict]:
-    from ..mc.dispatch import reason_key, supported, tasks_supported
-    from ..mc.engine import run_scenarios
+    from ..mc.engine import route, run_scenarios
 
     scs = [c.to_scenario() for c in grid]
     # Per-cell gate refusal keys (None = batched): fallback rows carry
     # theirs as ``fallback_reason`` so a sweep that silently routes
     # most cells to the scalar path never reads as "batched".
-    reasons: list[Optional[str]] = []
-    for sc in scs:
-        why = supported(sc)
-        reasons.append(None if why is None else reason_key(why))
-    jax_idx = [k for k in range(len(scs)) if reasons[k] is None]
-    # Build once here (shared with the kernel via ``prebuilt``) so the
-    # dynamic half of the gate can still demote caller-shaped streams.
-    prebuilt = [scs[k].workload.build() for k in jax_idx]
-    keep = []
-    for j, k in enumerate(jax_idx):
-        why = tasks_supported(prebuilt[j][0])
-        if why is None:
-            keep.append(j)
-        else:
-            reasons[k] = reason_key(why)
-    jax_idx = [jax_idx[j] for j in keep]
-    prebuilt = [prebuilt[j] for j in keep]
-
+    reasons, built = route(scs)
     rows: list[Optional[dict]] = [None] * len(grid)
-    if jax_idx:
-        batched = run_scenarios([scs[k] for k in jax_idx],
-                                prebuilt=prebuilt)
-        for k, res in zip(jax_idx, batched):
-            row = asdict(grid[k])
-            row.update(res.summary())
-            row["backend"] = "jax"
-            rows[k] = row
+    dev = sorted(built)
+    if dev:
+        batched = run_scenarios([scs[k] for k in dev],
+                                prebuilt=[built[k] for k in dev])
+        for k, res in zip(dev, batched):
+            rows[k] = {**asdict(grid[k]), **res.summary()}
     rest = [k for k in range(len(grid)) if rows[k] is None]
     if rest:
         for k, row in zip(rest, run_sweep([grid[k] for k in rest],
                                           parallel=parallel,
                                           processes=processes)):
-            row["backend"] = "python"
             row["fallback_reason"] = reasons[k]
             rows[k] = row
     return rows

@@ -84,7 +84,8 @@ def synthetic_measure(rows: Sequence[dict], seed: int = 0,
 
 def measure_kernels(repeats: int = 5, small: bool = True) -> list[dict]:
     """Compile-and-replay: feature rows with median wall-clock
-    ``measured_ms`` per compiled kernel. Requires jax."""
+    ``measured_ms`` per compiled kernel. Requires jax; off the TPU the
+    kernels run (and are timed) in the Pallas interpreter."""
     import time
 
     import jax
@@ -92,9 +93,10 @@ def measure_kernels(repeats: int = 5, small: bool = True) -> list[dict]:
     from ..launch.hlo_analysis import analyze
     from .features import _kernel_cases, compile_kernel
 
+    interpret = jax.default_backend() != "tpu"
     rows = []
     for name, builder in _kernel_cases(small):
-        compiled, args = compile_kernel(name, builder)
+        compiled, args = compile_kernel(name, builder, interpret=interpret)
         a = analyze(compiled.as_text())
         jax.block_until_ready(compiled(*args))  # warm the executable
         times = []

@@ -123,13 +123,14 @@ def _kernel_cases(small: bool = True) -> list[tuple]:
             ("fused_rmsnorm", rmsnorm)]
 
 
-def compile_kernel(name: str, builder):
+def compile_kernel(name: str, builder, *, interpret: bool):
     """Lower + compile one kernel case; returns ``(compiled, args)``.
     The compiled executable serves both sides of compile-and-replay:
     ``analyze(compiled.as_text())`` for features, timed invocation for
-    the calibrator's measurements."""
+    the calibrator's measurements. ``interpret`` selects the Pallas
+    interpreter (CPU hosts) over the TPU lowering."""
     fn, args = builder()
-    return fn.lower(*args).compile(), args
+    return fn.lower(*args, interpret=interpret).compile(), args
 
 
 def kernel_features(small: bool = True, ops_filter: Optional[list] = None,
@@ -137,15 +138,19 @@ def kernel_features(small: bool = True, ops_filter: Optional[list] = None,
     """FLOP/byte/trip rows for the compiled Pallas kernels.
 
     Requires jax; raises ImportError where it is absent (callers gate —
-    the synthetic calibration path needs no compiler at all).
+    the synthetic calibration path needs no compiler at all). Off the
+    TPU the kernels run in the Pallas interpreter.
     """
+    import jax
+
     from ..launch.hlo_analysis import analyze
 
+    interpret = jax.default_backend() != "tpu"
     rows = []
     for name, builder in _kernel_cases(small):
         if ops_filter is not None and name not in ops_filter:
             continue
-        compiled, args = compile_kernel(name, builder)
+        compiled, args = compile_kernel(name, builder, interpret=interpret)
         a = analyze(compiled.as_text())
         rows.append({
             "op": name,

@@ -1,6 +1,8 @@
 """repro.kernels — Pallas TPU kernels (+ jnp oracles in ref.py).
 
-Validated in interpret mode on CPU; BlockSpecs sized for v5e VMEM.
+Checked against the oracles in interpret mode on the CPU and compiled
+for a v5e chip (``tests/test_tpu_compile.py``); BlockSpecs sized for
+v5e VMEM.
 """
 from . import ref
 from .ops import (decode_attention, flash_attention, fused_rmsnorm,

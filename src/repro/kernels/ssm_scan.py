@@ -26,24 +26,27 @@ def _ssm_kernel(xb_ref, b_ref, c_ref, cum_ref, y_ref, h_ref, *,
     xb = xb_ref[0].astype(jnp.float32)          # (Q, hd)
     B = b_ref[0].astype(jnp.float32)            # (Q, ds)
     C = c_ref[0].astype(jnp.float32)            # (Q, ds)
-    cum = cum_ref[0].astype(jnp.float32)        # (Q,) within-chunk cumsum
-    tot = cum[-1]
+    cum_r = cum_ref[0, 0].astype(jnp.float32)   # (1, Q) within-chunk cumsum
+    tot = cum_r[:, chunk - 1:]                  # (1, 1)
 
-    # intra-chunk: L[i,j] = exp(cum_i - cum_j), j <= i
-    diff = cum[:, None] - cum[None, :]
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(jj <= ii, jnp.exp(diff), 0.0)
+    # the same values as a column, by a masked lane reduction (no
+    # transpose of a one-row tile)
+    cum_c = jnp.sum(jnp.where(ii == jj, cum_r, 0.0), axis=1,
+                    keepdims=True)              # (Q, 1)
+    # intra-chunk: L[i,j] = exp(cum_i - cum_j), j <= i
+    L = jnp.where(jj <= ii, jnp.exp(cum_c - cum_r), 0.0)
     sBB = C @ B.T                                # (Q, Q)
     y_intra = (sBB * L) @ xb                     # (Q, hd)
     # inter-chunk: state contribution decayed to each position
     h = h_ref[...]                               # (hd, ds)
-    y_inter = jnp.exp(cum)[:, None] * (C @ h.T)  # (Q, hd)
+    y_inter = jnp.exp(cum_c) * (C @ h.T)         # (Q, hd)
     y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
     # state update
-    decay_to_end = jnp.exp(tot - cum)            # (Q,)
+    decay_to_end = jnp.exp(tot - cum_c)          # (Q, 1)
     h_ref[...] = h * jnp.exp(tot) + \
-        (xb * decay_to_end[:, None]).T @ B       # (hd, ds)
+        (xb * decay_to_end).T @ B                # (hd, ds)
 
 
 def ssm_scan(xbar, B, C, cumlog, *, chunk: int = 64,
@@ -67,11 +70,11 @@ def ssm_scan(xbar, B, C, cumlog, *, chunk: int = 64,
             pl.BlockSpec((1, chunk, hd), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, ds), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, ds), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk), lambda b, c: (b, c)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda b, c: (b, c, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, hd), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, hd), xbar.dtype),
         scratch_shapes=[pltpu.VMEM((hd, ds), jnp.float32)],
         interpret=interpret,
-    )(xbar, B, C, cumlog)
+    )(xbar, B, C, cumlog.reshape(BH, nc, 1, chunk))  # one full row per chunk
     return y

@@ -8,11 +8,12 @@ Public surface (DESIGN.md Sec. 16):
 * :func:`supported` — the regime gate (None = batched, str = reason
   for scalar fallback).
 
-Bit-identity contract: under ``jax_enable_x64`` (entered per call via
-``jax.experimental.enable_x64`` — the repo's global dtype default is
-untouched) on the CPU backend the batched engine reproduces the
-scalar engine's per-task digests and every cost roll-up exactly.
-Other backends run but carry no bit-level promise.
+Equivalence contract: the engine runs under x64 (entered per call via
+``jax.enable_x64(True)`` — the repo's global dtype default is
+untouched). On the CPU backend it reproduces the scalar engine's
+per-task digests and every cost roll-up bit-for-bit. On the TPU, which
+emulates float64 as double-f32, integer observables are exact and
+floats agree within ``kernels.float_rtol`` (DESIGN.md Sec. 16).
 
 Heavy imports (jax) are deferred until first use so ``import repro``
 stays light.

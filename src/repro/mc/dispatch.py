@@ -154,41 +154,33 @@ def tasks_supported(tasks) -> Optional[Refusal]:
 
 # -- persistent compilation cache ----------------------------------------------
 
-_CACHE_DIR: Optional[str] = None
+# The cache's fallback home: a fixed directory at the checkout root (a
+# cache that moves never hits).
+_DEFAULT_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".jax_cache"))
 
 
-def enable_compile_cache(path: Optional[str] = None) -> Optional[str]:
-    """Opt in to JAX's persistent compilation cache.
+def enable_compile_cache() -> str:
+    """Keep compiled (C, N)-bucket programs in JAX's persistent
+    compilation cache, so they survive process restarts; returns the
+    cache directory.
 
-    ``path`` wins; otherwise the ``REPRO_MC_COMPILE_CACHE`` env var is
-    consulted.  Returns the active cache directory (None when neither
-    is set — caching stays off, the historical default).  Idempotent:
-    the first enabled directory sticks for the process, matching
-    JAX's own one-shot config.  Compiled (C, N)-bucket programs then
-    survive process restarts, which removes the ~8 s ``jax_cold``
-    penalty from smoke-scale runs (ISSUE 9 satellite).
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read
+    it and no other directory is set here; otherwise the cache is the
+    fixed ``.jax_cache/`` at the checkout root.
     """
-    global _CACHE_DIR
-    if _CACHE_DIR is not None:
-        return _CACHE_DIR
-    path = path or os.environ.get("REPRO_MC_COMPILE_CACHE")
-    if not path:
-        return None
     import jax
 
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    # Bucket programs compile in ~1 s; without this floor the cache
-    # would skip exactly the programs we want it to keep.
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    _CACHE_DIR = path
+    path = jax.config.jax_compilation_cache_dir
+    if not path:
+        path = _DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
     return path
 
 
-def compile_cache_entries() -> Optional[int]:
-    """Number of entries in the active persistent cache (None when
-    caching is off) — benches diff this across a run to attribute
-    wall-clock to recompiles vs kernel slowdowns."""
-    if _CACHE_DIR is None or not os.path.isdir(_CACHE_DIR):
-        return None
-    return len(os.listdir(_CACHE_DIR))
+def compile_cache_entries() -> int:
+    """Number of entries in the persistent cache — benches diff this
+    across a run to tell a recompile from a cache hit."""
+    path = enable_compile_cache()
+    return len(os.listdir(path)) if os.path.isdir(path) else 0

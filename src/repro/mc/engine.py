@@ -19,7 +19,8 @@ from typing import TYPE_CHECKING, Optional, Sequence
 
 import numpy as np
 
-from .dispatch import enable_compile_cache, supported, tasks_supported
+from .dispatch import (enable_compile_cache, reason_key, supported,
+                       tasks_supported)
 
 if TYPE_CHECKING:
     from ..scenario import Scenario, ScenarioResult
@@ -100,6 +101,30 @@ def _fleet_result(sc: "Scenario", tasks, sel: list[int]):
                      for j, i in enumerate(sel)],
         node_meta=meta,
     )
+
+
+def route(scenarios: Sequence["Scenario"]
+          ) -> tuple[list[Optional[str]], dict[int, tuple]]:
+    """Partition cells between the engines before any work happens.
+
+    Returns the per-cell refusal key (None for a batched cell, else a
+    stable ``Refusal.key`` counter key) and, for every batched cell
+    index, its built ``(tasks, meta)`` — handed on to
+    ``run_scenarios(prebuilt=...)`` so each workload is built once.
+    The dynamic half of the gate can still demote a caller-shaped
+    stream here.
+    """
+    reasons: list[Optional[str]] = []
+    built: dict[int, tuple] = {}
+    for k, sc in enumerate(scenarios):
+        why = supported(sc)
+        if why is None:
+            tasks, meta = sc.workload.build()
+            why = tasks_supported(tasks)
+            if why is None:
+                built[k] = (tasks, meta)
+        reasons.append(None if why is None else reason_key(why))
+    return reasons, built
 
 
 def run_scenarios(scenarios: Sequence["Scenario"],
@@ -211,5 +236,6 @@ def run_scenarios(scenarios: Sequence["Scenario"],
                 total_ctx=sum(t.ctx_switches for t in tasks))
         results.append(ScenarioResult(
             scenario=sc, raw=raw, meta=dict(meta),
-            mc_stats={"iters": iters[k], "events": events[k]}))
+            mc_stats={"iters": iters[k], "events": events[k]},
+            backend="jax"))
     return results

@@ -44,20 +44,25 @@ as fixed-length ``lax.scan`` batches:
 Barrier events (arrivals in tid order, the minimal FIFO expiry) are
 then re-serialized exactly as the heap would.
 
-Bit-identity contract: under ``jax_enable_x64`` on the CPU backend
-every float is computed by the SAME operation sequence as the scalar
-engine — the shared pure helpers of ``core/events.py``
-(`chunk_run_ms`, `chunk_end_ms`, `cfs_slice_ms`, `fifo_budget_ms`)
-re-bound to ``jnp.minimum``/``jnp.maximum`` — so per-task digests
-(completion, first_run, preemptions, ctx_switches, migrations,
-cpu_time) and every cost roll-up derived from them match the scalar
-engine bit-for-bit.  The multi-event batches preserve the contract
-because they only ever retire FULL chunks whose parameters the event
-path would compute identically: end times accumulate through an
-explicit left fold ``e = (e + ctx) + run`` inside ``lax.scan`` —
-NEVER ``cumsum``, which XLA may reassociate — and the only
-associative scans used for predicates are exact ones (integer
-``cumsum`` of completion flags, ``cummax`` of push keys).
+Equivalence contract (DESIGN.md Sec. 16). Under ``jax.enable_x64``
+on the CPU backend every float is computed by the SAME operation
+sequence as the scalar engine — the shared pure helpers of
+``core/events.py`` (`chunk_run_ms`, `chunk_end_ms`, `cfs_slice_ms`,
+`fifo_budget_ms`) re-bound to ``jnp.minimum``/``jnp.maximum`` — so
+per-task digests (completion, first_run, preemptions, ctx_switches,
+migrations, cpu_time) and every cost roll-up derived from them match
+the scalar engine bit-for-bit.  The multi-event batches preserve the
+contract because they only ever retire FULL chunks whose parameters
+the event path would compute identically: end times accumulate
+through an explicit left fold ``e = (e + ctx) + run`` inside
+``lax.scan`` — NEVER ``cumsum``, which XLA may reassociate — and the
+only associative scans used for predicates are exact ones (integer
+``cumsum`` of completion flags, ``cummax`` of push keys).  The TPU has
+no IEEE float64: its compiler runs the same program in double-f32
+(pairs of f32), so there the floats agree within :func:`float_rtol`.
+The integer observables stay exact there because the one float whose
+rounding steers the trajectory, the CFS vruntime, is kept as integer
+IEEE bit patterns (`vr_add`), bit-for-bit on every backend.
 
 A plain-FIFO cell runs as the hybrid machinery with ``n_fifo == C``
 and an infinite budget: ``min(rem, inf) == rem`` and ``max(inf - 0.0,
@@ -70,6 +75,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.core.events import (_EPS, cfs_slice_ms, chunk_completes,
@@ -85,6 +91,57 @@ CTX_SWITCH_MS = 0.06
 
 _INF = float("inf")
 _I32MAX = 2 ** 31 - 1
+
+# Vruntimes are kept as the int64 bit patterns of their IEEE-754
+# binary64 values ("vr bits"); see `vr_add`. For non-negative doubles
+# the bit-pattern order is the value order, so every vruntime compare,
+# min/max, cummax and sort runs on exact integers on any backend.
+# Sentinels: _VR_TOP sorts after every vruntime, _VR_BOT before.
+_VR_TOP = np.iinfo(np.int64).max
+_VR_BOT = -1
+# The CFS slice granted at a pick, as vr bits, per post-pop runqueue
+# length k: latency / k floored at the granularity, constant from
+# k = latency // granularity on. A pushed-back chunk always ran one full
+# slice, so these are the only increments a vruntime ever receives.
+_SLICE_KMAX = int(SCHED_LATENCY_MS // MIN_GRANULARITY_MS)
+_SLICE_BITS = np.array(
+    [cfs_slice_ms(k, SCHED_LATENCY_MS, MIN_GRANULARITY_MS)
+     for k in range(_SLICE_KMAX + 1)], np.float64).view(np.int64)
+_FRAC = (1 << 52) - 1
+_HIDDEN = 1 << 52
+
+
+def vr_add(a, b):
+    """IEEE-754 binary64 sum ``a + b`` of two non-negative finite
+    doubles given as int64 bit patterns, rounded to nearest-even —
+    bit-for-bit what a float64 add returns, computed in integers.
+
+    The TPU has no IEEE float64 (its compiler runs f64 as pairs of f32),
+    and CFS pick order hangs on vruntime near-ties whose IEEE rounding
+    the scalar engine's answer depends on; integer arithmetic is exact
+    everywhere. Operands are 0 or normal numbers (vruntimes are sums of
+    slices >= the granularity).
+    """
+    hi = jnp.maximum(a, b)
+    lo = jnp.minimum(a, b)
+    e = hi >> 52
+    # 53-bit significands with 3 guard/round/sticky bits below.
+    ma = ((hi & _FRAC) | _HIDDEN) << 3
+    mb = ((lo & _FRAC) | _HIDDEN) << 3
+    d = jnp.minimum(e - (lo >> 52), 60)
+    sticky = (mb & ((jnp.ones_like(mb) << d) - 1)) != 0
+    s = ma + ((mb >> d) | sticky.astype(mb.dtype))
+    carry = s >> 56                       # the sum overflowed 53 bits
+    s = (s >> carry) | (s & carry)
+    e = e + carry
+    low = s & 7
+    m = s >> 3
+    m = m + ((low > 4) | ((low == 4) & ((m & 1) == 1))).astype(m.dtype)
+    ovf = m >> 53                         # rounding carried out
+    m = m >> ovf
+    out = ((e + ovf) << 52) | (m & _FRAC)
+    return jnp.where(lo == 0, hi, out)
+
 
 # Safety valve: an upper bound on outer-loop iterations so a regime
 # bug hangs nothing — the engine checks the `ok` output and raises.
@@ -124,6 +181,11 @@ def make_cell_kernel(n_cores: int, n_slots: int):
     W = _WINDOW_SMALL if N <= 128 else _WINDOW_DEEP
 
     cids = jnp.arange(C, dtype=jnp.int32)
+    slice_bits_tab = jnp.asarray(_SLICE_BITS)
+
+    def slice_bits(k):
+        """The slice ``cfs_slice_ms(k)`` as vr bits."""
+        return slice_bits_tab[jnp.clip(k, 0, _SLICE_KMAX)]
 
     def kernel(arrival, service, n_tasks, n_fifo, limit):
         """Run one cell to completion.
@@ -140,7 +202,7 @@ def make_cell_kernel(n_cores: int, n_slots: int):
         st = dict(
             # per-task
             rem=service,
-            vr=jnp.zeros(N),
+            vr=jnp.zeros(N, jnp.int64),         # vr bits
             cpu=jnp.zeros(N),
             seq=jnp.zeros(N, jnp.int32),
             qcore=jnp.zeros(N, jnp.int32),
@@ -155,8 +217,11 @@ def make_cell_kernel(n_cores: int, n_slots: int):
             cur=jnp.full(C, -1, jnp.int32),
             end=jnp.full(C, _INF),
             clen=jnp.zeros(C),
+            # the in-flight chunk's slice as vr bits: what a pushback
+            # adds to the task's vruntime
+            cslb=jnp.zeros(C, jnp.int64),
             last=jnp.full(C, -1, jnp.int32),
-            minvr=jnp.zeros(C),
+            minvr=jnp.zeros(C, jnp.int64),
             seqc=jnp.zeros(C, jnp.int32),
             rqn=jnp.zeros(C, jnp.int32),
             # scalars
@@ -197,7 +262,7 @@ def make_cell_kernel(n_cores: int, n_slots: int):
                 (st["qcore"][None, :] == cids[:, None])
             skey = jnp.where(member, st["seq"][None, :], _I32MAX)
             p1 = jnp.argsort(skey, axis=1, stable=True)
-            vkey = jnp.where(member, st["vr"][None, :], _INF)
+            vkey = jnp.where(member, st["vr"][None, :], _VR_TOP)
             vg = jnp.take_along_axis(vkey, p1, axis=1)
             p2 = jnp.argsort(vg, axis=1, stable=True)
             return jnp.take_along_axis(p1, p2, axis=1).astype(jnp.int32)
@@ -213,7 +278,7 @@ def make_cell_kernel(n_cores: int, n_slots: int):
             """
             stat, qcore, vr, seq = st["stat"], st["qcore"], st["vr"], st["seq"]
             member = (stat[None, :] == 2) & (qcore[None, :] == cids[:, None])
-            vkey = jnp.where(member, vr[None, :], _INF)
+            vkey = jnp.where(member, vr[None, :], _VR_TOP)
             vmin = jnp.min(vkey, axis=1)
             tie = member & (vkey == vmin[:, None])
             skey = jnp.where(tie, seq[None, :], _I32MAX)
@@ -231,6 +296,7 @@ def make_cell_kernel(n_cores: int, n_slots: int):
             # slice_for reads nr_running AFTER the pop, core.task still
             # unset: nr == len(rq) == rqn.
             slc = cfs_slice_ms(rqn, LAT, GRAN, _max=jnp.maximum)
+            slb = slice_bits(rqn)
             ctx = jnp.where(ctx_ref == ntid, 0.0, CTX)
             gat = jnp.where(pickm, ntid, 0)
             fr_v = st["fr"][gat]
@@ -247,7 +313,8 @@ def make_cell_kernel(n_cores: int, n_slots: int):
                         rqn=rqn,
                         cur=jnp.where(pickm, ntid, st["cur"]),
                         end=jnp.where(pickm, nend, st["end"]),
-                        clen=jnp.where(pickm, run, st["clen"])), pickm
+                        clen=jnp.where(pickm, run, st["clen"]),
+                        cslb=jnp.where(pickm, slb, st["cslb"])), pickm
 
         # -- step 1: stable-alternation-cycle fast-forward ------------
         # A CFS core whose runnable set is small (k = rqn + 1 <= KC
@@ -274,11 +341,13 @@ def make_cell_kernel(n_cores: int, n_slots: int):
             # size — and with it the slice and the ctx charge — is
             # invariant across the whole scan.
             s = cfs_slice_ms(rqn, LAT, GRAN, _max=jnp.maximum)
+            sb = slice_bits(rqn)
             ctx = jnp.where(rqn > 0, CTX, 0.0)
             kk = jnp.maximum(rqn + 1, 1)
             fr0 = st["fr"][safe]
             cy = dict(
-                e=st["end"], L=st["clen"], m=jnp.zeros(C, jnp.int32),
+                e=st["end"], L=st["clen"], Lb=st["cslb"],
+                m=jnp.zeros(C, jnp.int32),
                 rem=st["rem"][safe], vr=st["vr"][safe],
                 # Seed with the live totals: each fire then does ONE
                 # left-chained `cpu + L` exactly like the scalar
@@ -298,12 +367,12 @@ def make_cell_kernel(n_cores: int, n_slots: int):
                 r0 = jnp.take_along_axis(cy["rem"], m[:, None], 1)[:, 0]
                 v0 = jnp.take_along_axis(cy["vr"], m[:, None], 1)[:, 0]
                 r2 = r0 - L
-                v2 = v0 + L
+                v2 = vr_add(v0, cy["Lb"])
                 # Stability: the pushback must land at the tail — at
                 # or after every queued member's key (the push's seq is
                 # fresher, so an equal vruntime still sorts after).
-                qmax = jnp.max(jnp.where(valid & ~onem, cy["vr"], -_INF),
-                               axis=1)
+                qmax = jnp.max(jnp.where(valid & ~onem, cy["vr"],
+                                         _VR_BOT), axis=1)
                 fire = cy["alive"] & (r2 > _EPS) & (v2 >= qmax) & \
                     bb(e, ta, tf, fcid)
                 fm = fire[:, None] & onem
@@ -321,6 +390,7 @@ def make_cell_kernel(n_cores: int, n_slots: int):
                 return dict(
                     e=jnp.where(fire, e2, e),
                     L=jnp.where(fire, run2, L),
+                    Lb=jnp.where(fire, sb, cy["Lb"]),
                     m=jnp.where(fire, m2, m),
                     rem=rem_u, vr=vr_u,
                     cpu=jnp.where(fm, cy["cpu"] + L[:, None], cy["cpu"]),
@@ -370,6 +440,7 @@ def make_cell_kernel(n_cores: int, n_slots: int):
                 last=jnp.where(did, last2, st["last"]),
                 end=jnp.where(did, cy["e"], st["end"]),
                 clen=jnp.where(did, cy["L"], st["clen"]),
+                cslb=jnp.where(did, cy["Lb"], st["cslb"]),
                 minvr=jnp.where(did, cy["mv"], st["minvr"]),
                 seqc=st["seqc"] + jnp.where(did, cy["c"], 0),
                 ev=st["ev"] + jnp.sum(jnp.where(did, cy["c"], 0),
@@ -411,11 +482,12 @@ def make_cell_kernel(n_cores: int, n_slots: int):
             s_i = cfs_slice_ms(k1[:, None] - cumx, LAT, GRAN,
                                _max=jnp.maximum)
             slice_ok = (s_i == s[:, None]) | (ii == 0)
-            pushed = vr0 + runs
+            pushed = vr_add(vr0, jnp.where(ii == 0, st["cslb"][:, None],
+                                           slice_bits(k1)[:, None]))
             # Stability: a non-completing pushback must land at the
             # tail — at/after the deepest original key and every
             # earlier in-window push (exact cummax).
-            pkey = jnp.where(comp, -_INF, pushed)
+            pkey = jnp.where(comp, _VR_BOT, pushed)
             # Deepest original key: rotation is sorted, so the last
             # queue entry (possibly beyond the window) carries it.
             tail_tid = jnp.take_along_axis(
@@ -491,6 +563,7 @@ def make_cell_kernel(n_cores: int, n_slots: int):
                 last=jnp.where(did, u_cp, st["last"]),
                 end=jnp.where(did, end_c, st["end"]),
                 clen=jnp.where(did, run_c, st["clen"]),
+                cslb=jnp.where(did, slice_bits(k1 - cumc), st["cslb"]),
                 # pops ratchet min_vruntime through nondecreasing keys:
                 # the iterated max equals one max against the last pop.
                 minvr=jnp.where(did,
@@ -517,7 +590,7 @@ def make_cell_kernel(n_cores: int, n_slots: int):
             pb = elig & ~d                      # pushback (chunk limit)
             de = elig & d                       # completion
             pidx = jnp.where(pb, cur, N)
-            vr2 = st["vr"][tid] + L
+            vr2 = vr_add(st["vr"][tid], st["cslb"])
             st = dict(
                 st,
                 rem=st["rem"].at[sidx].set(jnp.where(d, 0.0, rem2),
@@ -745,6 +818,19 @@ def make_cell_kernel(n_cores: int, n_slots: int):
     return kernel
 
 
+def float_rtol(n_events: int) -> float:
+    """Relative tolerance of a float observable on a backend without
+    IEEE float64 (DESIGN.md Sec. 16, "Equivalence tiers").
+
+    The TPU compiler rewrites f64 into pairs of f32 (double-f32), whose
+    add/multiply carry a relative error of up to about 2**-44 instead
+    of IEEE's 2**-53. Every float a cell reports is the end of a chain
+    of at most ``n_events`` such operations on non-negative times, so
+    its relative error is bounded by ``n_events * 2**-44``.
+    """
+    return max(int(n_events), 1) * 2.0 ** -44
+
+
 # One compiled program per (C, N) shape bucket; each call batches an
 # arbitrary number of cells along the leading axis.
 _GRID_CACHE: dict = {}
@@ -769,10 +855,8 @@ def run_grid(arrival, service, n_tasks, n_fifo, limit, *, n_cores: int):
     Returns a dict of [B, N] observable arrays (see ``make_cell_kernel``)
     as NumPy, computed under x64 on whatever backend JAX selected.
     """
-    from jax.experimental import enable_x64
-
     n_slots = arrival.shape[1]
-    with enable_x64():
+    with jax.enable_x64(True):
         fn = grid_kernel(n_cores, n_slots)
         out = fn(jnp.asarray(arrival, jnp.float64),
                  jnp.asarray(service, jnp.float64),

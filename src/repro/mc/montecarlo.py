@@ -17,8 +17,6 @@ from dataclasses import dataclass, field, replace
 from typing import TYPE_CHECKING, Optional, Sequence
 
 from ..traces.azure import TraceSpec
-from .dispatch import (enable_compile_cache, reason_key, supported,
-                       tasks_supported)
 
 if TYPE_CHECKING:
     from ..scenario import Scenario, ScenarioResult
@@ -30,30 +28,12 @@ class MonteCarloResult:
     seeds: tuple
     loads: tuple
     meta: dict = field(default_factory=dict)
-    # Per-cell gate refusal keys, aligned with ``results``: None for
-    # batched cells, a stable counter key (see ``dispatch.Refusal``)
-    # for cells the gate demoted, "forced" under backend="python".
-    reasons: tuple = ()
 
     @property
     def rows(self) -> list[dict]:
-        out = []
-        k = 0
-        for seed in self.seeds:
-            for load in self.loads:
-                r = self.results[k]
-                row = dict(seed=seed, load_scale=load,
-                           backend=self.meta["backends"][k])
-                why = self.reasons[k] if self.reasons else None
-                if why is not None and why != "forced":
-                    # Only genuine gate demotions are annotated — a
-                    # forced scalar baseline must stay row-identical
-                    # to its batched twin (the equivalence contract).
-                    row["fallback_reason"] = why
-                row.update(r.summary())
-                out.append(row)
-                k += 1
-        return out
+        cells = [(seed, load) for seed in self.seeds for load in self.loads]
+        return [{"seed": seed, "load_scale": load, **r.summary()}
+                for (seed, load), r in zip(cells, self.results)]
 
 
 @dataclass
@@ -72,10 +52,6 @@ class MonteCarlo:
     seeds: Sequence[int] = (0,)
     loads: Sequence[float] = (1.0,)
     backend: str = "jax"
-    # Opt-in persistent XLA compilation cache directory (also settable
-    # process-wide via the REPRO_MC_COMPILE_CACHE env var): compiled
-    # bucket programs survive restarts, removing the jax_cold penalty.
-    compile_cache_dir: Optional[str] = None
 
     def cells(self) -> list["Scenario"]:
         wl = self.scenario.workload
@@ -94,54 +70,30 @@ class MonteCarlo:
 
     def run(self) -> MonteCarloResult:
         from ..scenario import run as run_scalar
-        from .engine import run_scenarios
+        from .engine import route, run_scenarios
 
         cells = self.cells()
-        backends = []
-        use_jax = []
-        reasons: list[Optional[str]] = []
         if self.backend == "jax":
-            enable_compile_cache(self.compile_cache_dir)
-            for sc in cells:
-                why = supported(sc)
-                use_jax.append(why is None)
-                backends.append("jax" if why is None else "python")
-                reasons.append(None if why is None else reason_key(why))
+            reasons, built = route(cells)
         elif self.backend == "python":
-            use_jax = [False] * len(cells)
-            backends = ["python"] * len(cells)
-            reasons = ["forced"] * len(cells)
+            reasons, built = ["forced"] * len(cells), {}
         else:
             raise ValueError(f"unknown backend {self.backend!r}")
 
         results: list[Optional["ScenarioResult"]] = [None] * len(cells)
-        jax_idx = [k for k, u in enumerate(use_jax) if u]
-        if jax_idx:
-            # Build workloads once per (seed, load): sharing the trace
-            # generation across cells is fine — build() is
-            # deterministic per spec and each cell gets its own list.
-            prebuilt = [cells[k].workload.build() for k in jax_idx]
-            # A caller-shaped task stream can still force a fallback.
-            keep = []
-            for j, k in enumerate(jax_idx):
-                why = tasks_supported(prebuilt[j][0])
-                if why is None:
-                    keep.append(j)
-                else:
-                    use_jax[k] = False
-                    backends[k] = "python"
-                    reasons[k] = reason_key(why)
-            jax_idx = [jax_idx[j] for j in keep]
-            prebuilt = [prebuilt[j] for j in keep]
-        if jax_idx:
-            for k, res in zip(jax_idx,
-                              run_scenarios([cells[k] for k in jax_idx],
-                                            prebuilt=prebuilt)):
+        dev = sorted(built)
+        if dev:
+            batched = run_scenarios([cells[k] for k in dev],
+                                    prebuilt=[built[k] for k in dev])
+            for k, res in zip(dev, batched):
                 results[k] = res
         for k, sc in enumerate(cells):
             if results[k] is None:
                 results[k] = run_scalar(sc)
+                if reasons[k] != "forced":
+                    results[k].fallback_reason = reasons[k]
 
+        backends = [r.backend for r in results]
         counts: dict[str, int] = {}
         for why in reasons:
             if why is not None:
@@ -150,6 +102,5 @@ class MonteCarlo:
             results=results, seeds=tuple(self.seeds),
             loads=tuple(self.loads),
             meta={"backends": backends,
-                  "fallback": sum(b == "python" for b in backends),
-                  "fallback_reasons": counts},
-            reasons=tuple(reasons))
+                  "fallback": backends.count("python"),
+                  "fallback_reasons": counts})

@@ -313,6 +313,11 @@ class ScenarioResult:
     # part of the v1 summary schema, so scalar and batched summaries
     # stay byte-identical.
     mc_stats: Optional[dict] = None
+    # The engine that produced the result and, for a cell the batched
+    # engine's gate demoted, the refusal key (summary keys ``backend``
+    # and ``fallback_reason``).
+    backend: str = "python"
+    fallback_reason: str = "none"
 
     @property
     def n_requests(self) -> int:
@@ -341,12 +346,11 @@ class ScenarioResult:
             "rejected_cost_usd": 0.0,
             "retry_wait_ms": 0.0, "degraded_ms": 0.0,
             "spot_savings_usd": 0.0,
-            # Cost-model substrate defaults (DESIGN.md Sec. 18): the
-            # scalar python engine, no fallback, learned state zeroed
-            # (ClusterResult overlays real values when the dispatcher
-            # carries an estimator; the sweep overrides backend/
-            # fallback_reason per row).
-            "backend": "python", "fallback_reason": "none",
+            # Cost-model substrate defaults (DESIGN.md Sec. 18): learned
+            # state zeroed (ClusterResult overlays real values when the
+            # dispatcher carries an estimator).
+            "backend": self.backend,
+            "fallback_reason": self.fallback_reason,
             "cost_coeff": 0.0, "cost_pred_err_ms": 0.0,
         })
         out.update(self.raw.summary())

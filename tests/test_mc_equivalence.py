@@ -5,10 +5,11 @@ reproduce the scalar engine's per-task observables and summary
 roll-ups BIT-FOR-BIT (x64 CPU), and everything out of regime must be
 refused by the static gate — never silently approximated.
 
-Fast tier: the gate itself and the python-backend front door (no JAX).
-Slow tier (``--slow``): compiled equivalence — smoke trace, golden
-battery across seeds x policies, sweep-backend parity, and a
-hypothesis sweep over randomized small task grids.
+Fast tier: the gate itself, the python-backend front door, and the
+compiled program on the smoke trace through both front doors
+(``run_scenarios``, ``MonteCarlo``). Slow tier (``--slow``): the
+golden battery across seeds x policies, sweep-backend parity, fleets,
+and a hypothesis sweep over randomized small task grids.
 """
 from dataclasses import replace
 
@@ -41,12 +42,25 @@ def digest(res):
                   for t in res.raw.tasks)
 
 
+def strip_backend(row):
+    """A summary row without the ``backend`` key — the one field that
+    names the engine and so differs between the two."""
+    return {k: v for k, v in row.items() if k != "backend"}
+
+
+def assert_same_summary(batched, scalar):
+    assert batched.summary()["backend"] == "jax"
+    assert scalar.summary()["backend"] == "python"
+    assert strip_backend(batched.summary()) == \
+        strip_backend(scalar.summary())
+
+
 def assert_bit_identical(sc):
     from repro.mc.engine import run_scenarios
     scalar = repro.run(sc)
     batched = run_scenarios([sc])[0]
     assert digest(batched) == digest(scalar)
-    assert batched.summary() == scalar.summary()
+    assert_same_summary(batched, scalar)
 
 
 # -- fast tier: the static regime gate -----------------------------------------
@@ -174,12 +188,47 @@ def test_run_scenarios_refuses_out_of_regime():
         run_scenarios([sc])
 
 
-# -- slow tier: compiled bit-identity ------------------------------------------
+# -- compiled bit-identity: the device program on the CPU backend -------------
 
-@pytest.mark.slow
 @pytest.mark.parametrize("policy", ["fifo", "cfs", "hybrid"])
 def test_smoke_equivalence(policy):
     assert_bit_identical(_scenario(policy))
+
+
+def _vruntime_pairs(kind, rng, n=4096):
+    import numpy as np
+    from repro.mc.kernels import _SLICE_BITS
+    slices = _SLICE_BITS.view(np.float64)
+    if kind == "slice_sums":      # what a vruntime really is
+        a = np.cumsum(rng.choice(slices, size=n))
+        b = rng.choice(slices, size=n)
+    elif kind == "wide_exponents":   # alignment shifts up to and past 60
+        a = rng.uniform(1, 2, n) * 2.0 ** rng.integers(-70, 70, n)
+        b = rng.uniform(1, 2, n) * 2.0 ** rng.integers(-70, 70, n)
+    else:                         # near-equal: carries and exact ties
+        a = rng.uniform(1, 2, n) * 2.0 ** rng.integers(0, 30, n)
+        b = np.nextafter(a, np.inf) * rng.choice([1.0, 0.5, 2.0 ** -52],
+                                                 size=n)
+        b[:8] = 0.0
+    return a, b
+
+
+@pytest.mark.parametrize("kind", ["slice_sums", "wide_exponents",
+                                  "near_equal"])
+def test_vr_add_is_ieee_double_add(kind):
+    """Vruntimes live as int64 bit patterns on the device; their integer
+    add must round exactly like an IEEE float64 add, in either operand
+    order."""
+    import jax
+    import numpy as np
+    from repro.mc.kernels import vr_add
+    a, b = _vruntime_pairs(kind, np.random.default_rng(0))
+    want = (a + b).view(np.int64)
+    with jax.enable_x64(True):
+        for x, y in ((a, b), (b, a)):
+            got = np.asarray(jax.jit(vr_add)(x.view(np.int64),
+                                             y.view(np.int64)))
+            np.testing.assert_array_equal(got, want)
 
 
 @pytest.mark.slow
@@ -200,15 +249,37 @@ def test_golden_battery(policy, seed):
     assert_bit_identical(_scenario(policy, n_cores=8, trace=trace))
 
 
-@pytest.mark.slow
 def test_montecarlo_jax_matches_python():
     base = _scenario("hybrid")
     kw = dict(seeds=(0, 1), loads=(0.5, 1.5))
     jax_rows = MonteCarlo(base, backend="jax", **kw).run().rows
     py_rows = MonteCarlo(base, backend="python", **kw).run().rows
     assert [r["backend"] for r in jax_rows] == ["jax"] * 4
-    strip = lambda r: {k: v for k, v in r.items() if k != "backend"}
-    assert [strip(r) for r in jax_rows] == [strip(r) for r in py_rows]
+    assert [r["fallback_reason"] for r in jax_rows] == ["none"] * 4
+    assert [strip_backend(r) for r in jax_rows] == \
+        [strip_backend(r) for r in py_rows]
+
+
+def test_sweep_mixed_grid_spawns_its_scalar_pool(monkeypatch):
+    """The jax route runs its device program in the parent first; the
+    refused cells then go to a pool that must be spawned, never
+    forked from a process holding a JAX client."""
+    import multiprocessing as mp
+    from repro.cluster.sweep import build_grid, run_sweep
+    methods = []
+    get_context = mp.get_context
+    monkeypatch.setattr(mp, "get_context",
+                        lambda method=None: methods.append(method)
+                        or get_context(method))
+    grid = build_grid(("cfs", "hybrid"), ["none", "least_loaded"], [1],
+                      cores_per_node=4, minutes=1,
+                      invocations_per_min=60.0, n_functions=10, seed=0)
+    rows = run_sweep(grid, backend="jax", processes=2)
+    assert methods == ["spawn"]
+    assert [(r["backend"], r["fallback_reason"]) for r in rows] == [
+        ("jax", "none"), ("python", "fleet_dispatcher")] * 2
+    assert [strip_backend(r) for r in rows if r["backend"] == "jax"] == \
+        [strip_backend(r) for r in run_sweep(grid[::2], parallel=False)]
 
 
 @pytest.mark.slow
@@ -233,8 +304,8 @@ def test_sweep_backend_parity():
     py = run_sweep(grid, parallel=False)
     jx = run_sweep(grid, parallel=False, backend="jax")
     assert [r["backend"] for r in jx] == ["jax"] * len(jx)
-    strip = lambda r: {k: v for k, v in r.items() if k != "backend"}
-    assert [strip(r) for r in jx] == [strip(r) for r in py]
+    assert [strip_backend(r) for r in jx] == \
+        [strip_backend(r) for r in py]
 
 
 # -- slow tier: the newly-admitted fleet class (ISSUE 9) -----------------------
@@ -254,7 +325,7 @@ def test_fleet_golden_battery(policy, dispatcher):
     scalar = repro.run(sc)
     batched = run_scenarios([sc])[0]
     assert digest(batched) == digest(scalar)
-    assert batched.summary() == scalar.summary()
+    assert_same_summary(batched, scalar)
     assert batched.raw.assignments == scalar.raw.assignments
     assert batched.raw.node_ids == scalar.raw.node_ids
     assert batched.raw.node_policies == scalar.raw.node_policies
@@ -270,8 +341,8 @@ def test_montecarlo_fleet_cells_ride_the_device():
     assert out.meta["backends"] == ["jax", "jax"]
     assert out.meta["fallback_reasons"] == {}
     py = MonteCarlo(sc, backend="python", **kw).run()
-    strip = lambda r: {k: v for k, v in r.items() if k != "backend"}
-    assert [strip(r) for r in out.rows] == [strip(r) for r in py.rows]
+    assert [strip_backend(r) for r in out.rows] == \
+        [strip_backend(r) for r in py.rows]
 
 
 # -- slow tier: randomized small grids (hypothesis) ----------------------------
@@ -308,7 +379,7 @@ def test_property_batched_matches_scalar():
         scalar = repro.run(sc)
         batched = run_scenarios([sc])[0]
         assert digest(batched) == digest(scalar)
-        assert batched.summary() == scalar.summary()
+        assert_same_summary(batched, scalar)
 
     check()
 
@@ -346,7 +417,7 @@ def test_property_multi_event_paths_exercised():
         scalar = repro.run(sc)
         batched = run_scenarios([sc])[0]
         assert digest(batched) == digest(scalar)
-        assert batched.summary() == scalar.summary()
+        assert_same_summary(batched, scalar)
         stats = batched.mc_stats
         assert stats["iters"] < stats["events"], \
             f"one-event pace: {stats} ({policy}, n={len(specs)})"
