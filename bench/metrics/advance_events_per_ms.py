@@ -1,0 +1,8 @@
+"""Events the ``advance`` layer retired in the traced window (the engine's
+``events_by_layer`` counter) per ms of device self time in its scope,
+``mc_advance``."""
+from benchkit import scopes
+
+
+def read(run):
+    return scopes.events_per_ms(run, "advance")

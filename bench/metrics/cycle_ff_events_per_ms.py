@@ -1,0 +1,8 @@
+"""Events the ``cycle_ff`` layer retired in the traced window (the engine's
+``events_by_layer`` counter) per ms of device self time in its scope,
+``mc_cycle_ff``."""
+from benchkit import scopes
+
+
+def read(run):
+    return scopes.events_per_ms(run, "cycle_ff")

@@ -1,0 +1,7 @@
+"""Device self time per while-loop trip of the ``mc_window_ff`` scope: its
+ops' self time in the traced window over the trips run there."""
+from benchkit import scopes
+
+
+def read(run):
+    return scopes.ms_per_trip(run, "mc_window_ff")

@@ -14,6 +14,7 @@ engine would have produced, bit-for-bit (DESIGN.md Sec. 16).
 """
 from __future__ import annotations
 
+import itertools
 import random
 from typing import TYPE_CHECKING, Optional, Sequence
 
@@ -21,6 +22,7 @@ import numpy as np
 
 from .dispatch import (enable_compile_cache, reason_key, supported,
                        tasks_supported)
+from .spans import EVENT_LAYERS, gc_spans, span
 
 if TYPE_CHECKING:
     from ..scenario import Scenario, ScenarioResult
@@ -29,6 +31,10 @@ _INF = float("inf")
 
 # Hybrid defaults mirrored from core.hybrid.HybridScheduler.
 _HYBRID_TIME_LIMIT_MS = 1633.0
+
+# Numbers the `run_scenarios` calls of this process; the `call` stat
+# ties the spans of one call together.
+_CALLS = itertools.count()
 
 
 def _bucket(n: int) -> int:
@@ -137,16 +143,36 @@ def run_scenarios(scenarios: Sequence["Scenario"],
     scales); otherwise each ``workload.build()`` runs here. Raises
     ``ValueError`` on out-of-regime scenarios — callers partition
     with ``dispatch.supported`` first.  Each result carries
-    ``mc_stats`` = ``{"iters", "events"}`` (kernel while-loop trips
-    and scheduling events retired for that cell, summed over fleet
-    units) — the algorithmic multi-event win stays visible even where
-    1-core wall-clock hides it.
+    ``mc_stats`` = ``{"iters", "events", "events_by_layer"}``, summed
+    over fleet units: kernel while-loop trips, scheduling events
+    retired, and those events split by the layer that retired them
+    (a dict keyed by ``spans.EVENT_LAYERS``: ``cycle_ff``,
+    ``window_ff``, ``advance``, ``micro``; its values add up to
+    ``events``) — the algorithmic multi-event win stays visible even
+    where 1-core wall-clock hides it.
+
+    Under ``jax.profiler`` the call writes host spans (``spans.span``),
+    each with the stat ``call``, this process's number of the call:
+
+    * ``mc.pack`` (also ``C``, ``N``, ``B``): filling one program's
+      ``[B, N]`` arrays;
+    * ``mc.launch`` (``C``, ``N``, ``B``): ``run_grid``, from the copy
+      to the device through the program to ``device_get``;
+    * ``mc.rebuild``: the ``Task`` writes of one program (``C``, ``N``,
+      ``B``), and one more for building the results;
+    * ``mc.gc`` (``generation``): every garbage collection inside the
+      call.
     """
+    enable_compile_cache()
+    with gc_spans():
+        return _run(scenarios, prebuilt, call=next(_CALLS))
+
+
+def _run(scenarios, prebuilt, call: int) -> list["ScenarioResult"]:
+    """``run_scenarios``'s work; ``call`` is the spans' ``call`` stat."""
     from ..core.metrics import SimResult
     from ..scenario import ScenarioResult
     from .kernels import run_grid
-
-    enable_compile_cache()
 
     built = []
     for k, sc in enumerate(scenarios):
@@ -187,22 +213,25 @@ def run_scenarios(scenarios: Sequence["Scenario"],
 
     iters = [0] * len(scenarios)   # kernel while-loop trips per cell
     events = [0] * len(scenarios)  # scheduling events retired per cell
+    by_layer = np.zeros((len(scenarios), len(EVENT_LAYERS)), np.int64)
     for (C, N), us in groups.items():
         B = len(us)
-        arrival = np.full((B, N), _INF)
-        service = np.full((B, N), 1.0)
-        n_tasks = np.zeros(B, np.int32)
-        n_fifo = np.zeros(B, np.int32)
-        limit = np.zeros(B)
-        for b, u in enumerate(us):
-            k, _i, idxs = units[u]
-            tasks = built[k][0]
-            arrival[b, :len(idxs)] = [tasks[j].arrival for j in idxs]
-            service[b, :len(idxs)] = [tasks[j].service for j in idxs]
-            n_tasks[b] = len(idxs)
-            n_fifo[b], limit[b] = cell_params(scenarios[k])
-        out = run_grid(arrival, service, n_tasks, n_fifo, limit,
-                       n_cores=C)
+        with span("pack", call=call, C=C, N=N, B=B):
+            arrival = np.full((B, N), _INF)
+            service = np.full((B, N), 1.0)
+            n_tasks = np.zeros(B, np.int32)
+            n_fifo = np.zeros(B, np.int32)
+            limit = np.zeros(B)
+            for b, u in enumerate(us):
+                k, _i, idxs = units[u]
+                tasks = built[k][0]
+                arrival[b, :len(idxs)] = [tasks[j].arrival for j in idxs]
+                service[b, :len(idxs)] = [tasks[j].service for j in idxs]
+                n_tasks[b] = len(idxs)
+                n_fifo[b], limit[b] = cell_params(scenarios[k])
+        with span("launch", call=call, C=C, N=N, B=B):
+            out = run_grid(arrival, service, n_tasks, n_fifo, limit,
+                           n_cores=C)
         if not bool(np.all(out["ok"])):
             bad = sorted({units[us[b]][0] for b in range(B)
                           if not out["ok"][b]})
@@ -210,32 +239,37 @@ def run_scenarios(scenarios: Sequence["Scenario"],
                 f"batched MC kernel failed to drain cells {bad} "
                 f"(iteration cap hit or tasks left unfinished) — "
                 f"regime bug, please report")
-        for b, u in enumerate(us):
-            k, _i, idxs = units[u]
-            tasks = built[k][0]
-            for pos, j in enumerate(idxs):
-                task = tasks[j]
-                task.completion = float(out["completion"][b, pos])
-                task.first_run = float(out["first_run"][b, pos])
-                task.preemptions = int(out["preemptions"][b, pos])
-                task.ctx_switches = int(out["ctx_switches"][b, pos])
-                task.migrations = int(out["migrations"][b, pos])
-                task.cpu_time = float(out["cpu_time"][b, pos])
-                task.remaining = 0.0
-            iters[k] += int(out["n_iters"][b])
-            events[k] += int(out["n_events"][b])
+        with span("rebuild", call=call, C=C, N=N, B=B):
+            for b, u in enumerate(us):
+                k, _i, idxs = units[u]
+                tasks = built[k][0]
+                for pos, j in enumerate(idxs):
+                    task = tasks[j]
+                    task.completion = float(out["completion"][b, pos])
+                    task.first_run = float(out["first_run"][b, pos])
+                    task.preemptions = int(out["preemptions"][b, pos])
+                    task.ctx_switches = int(out["ctx_switches"][b, pos])
+                    task.migrations = int(out["migrations"][b, pos])
+                    task.cpu_time = float(out["cpu_time"][b, pos])
+                    task.remaining = 0.0
+                iters[k] += int(out["n_iters"][b])
+                events[k] += int(out["n_events"][b])
+                by_layer[k] += out["events_by_layer"][b]
 
     results: list["ScenarioResult"] = []
-    for k, sc in enumerate(scenarios):
-        tasks, meta = built[k]
-        if sc.fleet.is_fleet:
-            raw = _fleet_result(sc, tasks, fleet_sel[k])
-        else:
-            raw = SimResult(
-                policy=sc.policy.name, tasks=tasks,
-                total_ctx=sum(t.ctx_switches for t in tasks))
-        results.append(ScenarioResult(
-            scenario=sc, raw=raw, meta=dict(meta),
-            mc_stats={"iters": iters[k], "events": events[k]},
-            backend="jax"))
+    with span("rebuild", call=call):
+        for k, sc in enumerate(scenarios):
+            tasks, meta = built[k]
+            if sc.fleet.is_fleet:
+                raw = _fleet_result(sc, tasks, fleet_sel[k])
+            else:
+                raw = SimResult(
+                    policy=sc.policy.name, tasks=tasks,
+                    total_ctx=sum(t.ctx_switches for t in tasks))
+            results.append(ScenarioResult(
+                scenario=sc, raw=raw, meta=dict(meta),
+                mc_stats={"iters": iters[k], "events": events[k],
+                          "events_by_layer": dict(zip(
+                              EVENT_LAYERS, map(int, by_layer[k])))},
+                backend="jax"))
     return results

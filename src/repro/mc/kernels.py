@@ -82,6 +82,8 @@ from repro.core.events import (_EPS, cfs_slice_ms, chunk_completes,
                                chunk_end_ms, chunk_run_ms,
                                fifo_budget_ms)
 
+from .spans import EVENT_LAYERS, SCOPES
+
 # Default Linux knobs of the supported regime (see module docstring);
 # the dispatch gate (repro.mc.dispatch) refuses cells that override
 # them, so baking them into the compiled program is safe.
@@ -230,6 +232,9 @@ def make_cell_kernel(n_cores: int, n_slots: int):
             rrc=jnp.int32(0),
             it=jnp.int32(0),
             ev=jnp.int32(0),
+            # events retired per layer, in EVENT_LAYERS order; written
+            # by the outer loop only, never read back into the state
+            evl=jnp.zeros(len(EVENT_LAYERS), jnp.int32),
         )
 
         def t_arr(st):
@@ -768,7 +773,14 @@ def make_cell_kernel(n_cores: int, n_slots: int):
             live = (st["ptr"] < n_tasks) | jnp.any(st["cur"] >= 0)
             return live & (st["it"] < max_it)
 
+        # Each retirement layer runs under its own named scope (the
+        # SCOPES of repro.mc.spans), so a device trace splits a trip by
+        # layer. `micro` calls the same advance functions, so an op's
+        # layer is the OUTERMOST scope of its name stack.
+        rotation_s, cycle_s, window_s, advance_s, micro_s = SCOPES
+
         def body(st):
+            evl, ev0 = st["evl"], st["ev"]
             st = dict(st, it=st["it"] + 1)
             ta = t_arr(st)
             tf, fcid, _ = fifo_candidate(st)
@@ -777,8 +789,11 @@ def make_cell_kernel(n_cores: int, n_slots: int):
             # of every unfired core are still the exact pop order the
             # window needs (fired-but-still-eligible cores fall to the
             # rotation-free generic advance this iteration).
-            rot = rotation(st)
-            st, cdid = cycle_ff(st, ta, tf, fcid, rot)
+            with jax.named_scope(rotation_s):
+                rot = rotation(st)
+            with jax.named_scope(cycle_s):
+                st, cdid = cycle_ff(st, ta, tf, fcid, rot)
+            ev1 = st["ev"]
 
             tf, fcid, anyf = fifo_candidate(st)
             e = st["end"]
@@ -787,14 +802,19 @@ def make_cell_kernel(n_cores: int, n_slots: int):
             do_f = anyf & ~any_cfs & (tf < ta)
             do_a = ~any_cfs & ~do_f & (st["ptr"] < n_tasks)
 
-            st_w, handled = window_ff(st, elig & ~cdid, ta, tf, fcid,
-                                      rot)
-            st_cfs = cfs_advance(st_w, elig & ~handled)
-            st_fifo = fifo_advance(st, fcid, tf)
-            st_arr = arrival_step(st, ta)
-            stn = _sel_tree(
-                any_cfs, st_cfs,
-                _sel_tree(do_f, st_fifo, _sel_tree(do_a, st_arr, st)))
+            with jax.named_scope(window_s):
+                st_w, handled = window_ff(st, elig & ~cdid, ta, tf, fcid,
+                                          rot)
+            with jax.named_scope(advance_s):
+                st_cfs = cfs_advance(st_w, elig & ~handled)
+                st_fifo = fifo_advance(st, fcid, tf)
+                st_arr = arrival_step(st, ta)
+                stn = _sel_tree(
+                    any_cfs, st_cfs,
+                    _sel_tree(do_f, st_fifo, _sel_tree(do_a, st_arr, st)))
+            # the window's events count only where its state is selected
+            ev_w = jnp.where(any_cfs, st_w["ev"] - ev1, 0)
+            ev2 = stn["ev"]
 
             # Micro-step chain: the sparse phases (arrival interleave,
             # FIFO expiries, unstable pushes) advance one event at a
@@ -802,9 +822,11 @@ def make_cell_kernel(n_cores: int, n_slots: int):
             # the sort-free one-event machinery amortizes the fixed
             # per-iteration cost (rotation sorts, cycle/window scans,
             # state selects) over several events.
-            stn = lax.fori_loop(0, _MICRO_STEPS,
-                                lambda _, s: micro(s), stn)
-            return stn
+            with jax.named_scope(micro_s):
+                stn = lax.fori_loop(0, _MICRO_STEPS,
+                                    lambda _, s: micro(s), stn)
+            return dict(stn, evl=evl + jnp.stack(
+                [ev1 - ev0, ev_w, ev2 - ev1 - ev_w, stn["ev"] - ev2]))
 
         out = lax.while_loop(cond, body, st)
         live = jnp.arange(N) < n_tasks
@@ -813,7 +835,8 @@ def make_cell_kernel(n_cores: int, n_slots: int):
         return dict(completion=out["comp"], first_run=out["fr"],
                     preemptions=out["npre"], ctx_switches=out["nctx"],
                     migrations=out["nmig"], cpu_time=out["cpu"],
-                    ok=ok, n_iters=out["it"], n_events=out["ev"])
+                    ok=ok, n_iters=out["it"], n_events=out["ev"],
+                    events_by_layer=out["evl"])
 
     return kernel
 
@@ -834,15 +857,33 @@ def float_rtol(n_events: int) -> float:
 # One compiled program per (C, N) shape bucket; each call batches an
 # arbitrary number of cells along the leading axis.
 _GRID_CACHE: dict = {}
+# The shapes and dtypes of the arguments `run_grid` passed, by
+# (C, N, B), for `executables`.
+_RUN_ARGS: dict = {}
 
 
 def grid_kernel(n_cores: int, n_slots: int):
     key = (n_cores, n_slots)
     fn = _GRID_CACHE.get(key)
     if fn is None:
-        fn = jax.jit(jax.vmap(make_cell_kernel(n_cores, n_slots)))
+        kernel = make_cell_kernel(n_cores, n_slots)
+        # the HLO module's name, `jit_mc_grid_c<C>_n<N>`, tells the
+        # buckets' programs apart in a device trace
+        kernel.__name__ = kernel.__qualname__ = \
+            f"mc_grid_c{n_cores}_n{n_slots}"
+        fn = jax.jit(jax.vmap(kernel))
         _GRID_CACHE[key] = fn
     return fn
+
+
+def executables() -> dict:
+    """The compiled grid programs ``run_grid`` has run, by ``(C, N, B)``.
+    JAX's in-memory caches hand back the executable that ran, so
+    nothing compiles again. The HLO text of each (``.as_text()``) names
+    the scope of every device op in its ``op_name`` metadata."""
+    with jax.enable_x64(True):
+        return {(C, N, B): grid_kernel(C, N).lower(*args).compile()
+                for (C, N, B), args in sorted(_RUN_ARGS.items())}
 
 
 def run_grid(arrival, service, n_tasks, n_fifo, limit, *, n_cores: int):
@@ -853,14 +894,18 @@ def run_grid(arrival, service, n_tasks, n_fifo, limit, *, n_cores: int):
     limit            : f64[B]
 
     Returns a dict of [B, N] observable arrays (see ``make_cell_kernel``)
-    as NumPy, computed under x64 on whatever backend JAX selected.
+    and the per-cell counters ``ok``, ``n_iters``, ``n_events`` [B] and
+    ``events_by_layer`` [B, 4] (``spans.EVENT_LAYERS`` order), as NumPy,
+    computed under x64 on whatever backend JAX selected.
     """
-    n_slots = arrival.shape[1]
+    B, n_slots = arrival.shape
     with jax.enable_x64(True):
-        fn = grid_kernel(n_cores, n_slots)
-        out = fn(jnp.asarray(arrival, jnp.float64),
-                 jnp.asarray(service, jnp.float64),
-                 jnp.asarray(n_tasks, jnp.int32),
-                 jnp.asarray(n_fifo, jnp.int32),
-                 jnp.asarray(limit, jnp.float64))
+        args = (jnp.asarray(arrival, jnp.float64),
+                jnp.asarray(service, jnp.float64),
+                jnp.asarray(n_tasks, jnp.int32),
+                jnp.asarray(n_fifo, jnp.int32),
+                jnp.asarray(limit, jnp.float64))
+        _RUN_ARGS[(n_cores, n_slots, B)] = [
+            jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args]
+        out = grid_kernel(n_cores, n_slots)(*args)
         return {k: jax.device_get(v) for k, v in out.items()}

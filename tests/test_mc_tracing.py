@@ -1,0 +1,149 @@
+"""The batched engine's own instrumentation (``repro.mc.spans``).
+
+One small grid (C=2, N=64: one program) runs once under
+``jax.profiler`` on the CPU; the tests read its per-layer event
+counters, the scopes in the executed program's HLO, and the host spans
+in the trace. The instrumentation must not change a single observable.
+"""
+import gc
+import glob
+import os
+
+import numpy as np
+import pytest
+
+import repro
+from conftest import mk_tasks
+from repro import FleetSpec, PolicySpec, Scenario, WorkloadSpec
+from repro.mc.spans import EVENT_LAYERS, SCOPES, gc_spans
+
+C = 2
+POLICIES = {"fifo": {}, "cfs": {}, "hybrid": {"n_fifo": 1}}
+
+
+def _tasks(kind):
+    """``dense``: 40 tasks arriving inside half a second on 2 cores, so
+    runqueues go deep and the fast-forwards engage (the dense case of
+    ``test_mc_equivalence``); ``sparse``: 12 tasks over 2 s."""
+    rng = np.random.default_rng(3)
+    if kind == "dense":
+        arr, svc = rng.integers(0, 500, 40), rng.integers(50, 400, 40)
+    else:
+        arr, svc = rng.integers(0, 2000, 12), rng.integers(1, 400, 12)
+    return mk_tasks(sorted(zip(arr.astype(float), svc.astype(float))))
+
+
+def _scenario(kind, policy):
+    return Scenario(workload=WorkloadSpec(kind="tasks",
+                                          tasks=_tasks(kind)),
+                    fleet=FleetSpec(cores_per_node=C),
+                    policy=PolicySpec(name=policy, kw=POLICIES[policy]))
+
+
+CELLS = [(kind, p) for kind in ("dense", "sparse") for p in POLICIES]
+
+
+def _events(trace_dir):
+    """``(name, stats)`` of every host event named ``mc.*``."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    return [(e.name, dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("mc.")]
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """The grid's results and the ``mc.*`` events of its one traced
+    ``run_scenarios`` call."""
+    import jax
+    from repro.mc.engine import run_scenarios
+    d = str(tmp_path_factory.mktemp("trace"))
+    with jax.profiler.trace(d):
+        results = run_scenarios([_scenario(*c) for c in CELLS])
+    return results, _events(d)
+
+
+def test_events_by_layer_add_up_to_events(traced):
+    results, _ = traced
+    for r in results:
+        by = r.mc_stats["events_by_layer"]
+        assert list(by) == list(EVENT_LAYERS)
+        assert all(v >= 0 for v in by.values())
+        assert sum(by.values()) == r.mc_stats["events"]
+
+
+def test_fast_forwards_retire_events_on_dense_cfs(traced):
+    results, _ = traced
+    stats = results[CELLS.index(("dense", "cfs"))].mc_stats
+    assert stats["iters"] < stats["events"]
+    assert stats["events_by_layer"]["cycle_ff"] > 0
+    assert stats["events_by_layer"]["window_ff"] > 0
+
+
+@pytest.mark.parametrize("cell", CELLS, ids=["-".join(c) for c in CELLS])
+def test_instrumented_engine_is_bit_identical(traced, cell):
+    results, _ = traced
+    scalar = repro.run(_scenario(*cell))
+    batched = results[CELLS.index(cell)]
+
+    def digest(res):
+        return sorted((t.tid, repr(t.completion), repr(t.first_run),
+                       repr(t.cpu_time), t.preemptions, t.ctx_switches,
+                       t.migrations) for t in res.raw.tasks)
+
+    assert digest(batched) == digest(scalar)
+    strip = lambda row: {k: v for k, v in row.items() if k != "backend"}
+    assert strip(batched.summary()) == strip(scalar.summary())
+
+
+def test_executed_program_names_every_scope(traced):
+    from jax import monitoring
+    from repro.mc.kernels import executables
+    compiles = []
+
+    def on(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    monitoring.register_event_duration_secs_listener(on)
+    try:
+        ex = executables()[(C, 64, len(CELLS))]
+    finally:
+        monitoring.unregister_event_duration_listener(on)
+    assert compiles == []          # the program that ran, not a new one
+    text = ex.as_text()
+    assert "jit_mc_grid_c2_n64" in text
+    for scope in SCOPES:
+        assert f"/{scope}/" in text, scope
+
+
+def test_run_writes_pack_launch_rebuild_spans(traced):
+    _, events = traced
+    names = [n for n, _ in events]
+    for name in ("mc.pack", "mc.launch"):
+        assert names.count(name) == 1, names
+    # one per program and one for the results
+    assert names.count("mc.rebuild") == 2
+    calls = {st["call"] for _, st in events if "call" in st}
+    assert len(calls) == 1
+    for name, st in events:
+        if name in ("mc.pack", "mc.launch"):
+            assert (st["C"], st["N"], st["B"]) == (C, 64, len(CELLS))
+
+
+def test_gc_spans_wrap_a_collection(tmp_path):
+    import jax
+    hooks = len(gc.callbacks)
+    with jax.profiler.trace(str(tmp_path)):
+        with gc_spans():
+            assert len(gc.callbacks) == hooks + 1
+            gc.collect()
+        gc.collect()        # after the block: no span
+    assert len(gc.callbacks) == hooks     # the hook is gone
+    gen2 = [st for name, st in _events(str(tmp_path))
+            if name == "mc.gc" and st["generation"] == 2]
+    assert gen2 == [{"generation": 2}]
