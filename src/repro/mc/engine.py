@@ -156,8 +156,10 @@ def run_scenarios(scenarios: Sequence["Scenario"],
 
     * ``mc.pack`` (also ``C``, ``N``, ``B``): filling one program's
       ``[B, N]`` arrays;
-    * ``mc.launch`` (``C``, ``N``, ``B``): ``run_grid``, from the copy
-      to the device through the program to ``device_get``;
+    * ``mc.launch`` (``C``, ``N``, ``B``, and ``commit``, the form of
+      the window fast-forward's commit, ``kernels.window_commit``):
+      ``run_grid``, from the copy to the device through the program to
+      ``device_get``;
     * ``mc.rebuild``: the ``Task`` writes of one program (``C``, ``N``,
       ``B``), and one more for building the results;
     * ``mc.gc`` (``generation``): every garbage collection inside the
@@ -172,7 +174,7 @@ def _run(scenarios, prebuilt, call: int) -> list["ScenarioResult"]:
     """``run_scenarios``'s work; ``call`` is the spans' ``call`` stat."""
     from ..core.metrics import SimResult
     from ..scenario import ScenarioResult
-    from .kernels import run_grid
+    from .kernels import run_grid, window_commit
 
     built = []
     for k, sc in enumerate(scenarios):
@@ -229,7 +231,8 @@ def _run(scenarios, prebuilt, call: int) -> list["ScenarioResult"]:
                 service[b, :len(idxs)] = [tasks[j].service for j in idxs]
                 n_tasks[b] = len(idxs)
                 n_fifo[b], limit[b] = cell_params(scenarios[k])
-        with span("launch", call=call, C=C, N=N, B=B):
+        with span("launch", call=call, C=C, N=N, B=B,
+                  commit=window_commit(N)):
             out = run_grid(arrival, service, n_tasks, n_fifo, limit,
                            n_cores=C)
         if not bool(np.all(out["ok"])):

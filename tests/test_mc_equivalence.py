@@ -231,6 +231,86 @@ def test_vr_add_is_ieee_double_add(kind):
             np.testing.assert_array_equal(got, want)
 
 
+# -- the window fast-forward's two commit forms (kernels.window_commit) -------
+
+def _commit_battery(deep=False):
+    """fifo, cfs and hybrid on 2 cores, with fresh tasks on every call.
+    By default a dense and a sparse task set: one (C=2, N=64) program,
+    whose window fast-forward (W = 64) retires chunks on the dense cfs
+    cell. ``deep``: 150 tasks inside a second, one (2, 256) program
+    whose window holds W = 256 chunks."""
+    import numpy as np
+    rng = np.random.default_rng(3)
+    if deep:
+        sets = {"deep": (rng.integers(0, 1000, 150),
+                         rng.integers(50, 400, 150))}
+    else:
+        sets = {"dense": (rng.integers(0, 500, 40),
+                          rng.integers(50, 400, 40)),
+                "sparse": (rng.integers(0, 2000, 12),
+                           rng.integers(1, 400, 12))}
+    kw = {"fifo": {}, "cfs": {}, "hybrid": {"n_fifo": 1}}
+    return [Scenario(workload=WorkloadSpec(kind="tasks", tasks=mk_tasks(
+                sorted(zip(arr.astype(float), svc.astype(float))))),
+                     fleet=FleetSpec(cores_per_node=2),
+                     policy=PolicySpec(name=policy, kw=kw[policy]))
+            for arr, svc in sets.values() for policy in kw]
+
+
+@pytest.fixture(scope="module")
+def commit_forms():
+    """Both batteries' results under each commit form, by form and
+    ``deep``: the dense select both buckets take, and the scatters,
+    forced by a zero slot limit. Each form compiles its own programs
+    into a cache of its own, so later tests find the buckets' own."""
+    from repro.mc import kernels
+    from repro.mc.engine import run_scenarios
+    out = {}
+    for form, limit in (("dense", kernels._DENSE_COMMIT_MAX_SLOTS),
+                        ("scatter", 0)):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(kernels, "_DENSE_COMMIT_MAX_SLOTS", limit)
+            mp.setattr(kernels, "_GRID_CACHE", {})
+            assert kernels.window_commit(64) == form
+            assert kernels.window_commit(256) == form
+            out[form] = {deep: run_scenarios(_commit_battery(deep))
+                         for deep in (False, True)}
+    return out
+
+
+def _assert_forms_agree(commit_forms, form, deep):
+    """One form matches the scalar engine bit for bit (cpu_time
+    included) and the other form's counters: while-loop trips, events
+    and events by layer. Its window fast-forward retires chunks on the
+    cfs cell of the battery's first task set."""
+    got_all = commit_forms[form][deep]
+    other = commit_forms["scatter" if form == "dense" else "dense"][deep]
+
+    def digest_cpu(res):
+        return sorted((t.tid, repr(t.completion), repr(t.first_run),
+                       repr(t.cpu_time), t.preemptions, t.ctx_switches,
+                       t.migrations) for t in res.raw.tasks)
+
+    for sc, got, alt in zip(_commit_battery(deep), got_all, other):
+        scalar = repro.run(sc)
+        assert digest_cpu(got) == digest_cpu(scalar)
+        assert_same_summary(got, scalar)
+        assert got.mc_stats == alt.mc_stats
+    assert got_all[1].mc_stats["events_by_layer"]["window_ff"] > 0
+
+
+@pytest.mark.parametrize("form", ["dense", "scatter"])
+def test_window_commit_forms_are_bit_identical(commit_forms, form):
+    """The (2, 64) battery, window W = 64."""
+    _assert_forms_agree(commit_forms, form, deep=False)
+
+
+@pytest.mark.parametrize("form", ["dense", "scatter"])
+def test_deep_window_commit_forms_are_bit_identical(commit_forms, form):
+    """The (2, 256) battery, window W = 256."""
+    _assert_forms_agree(commit_forms, form, deep=True)
+
+
 @pytest.mark.slow
 def test_hybrid_knobs_equivalence():
     assert_bit_identical(_scenario("hybrid", n_fifo=1))

@@ -8,6 +8,7 @@ in the trace. The instrumentation must not change a single observable.
 import gc
 import glob
 import os
+import re
 
 import numpy as np
 import pytest
@@ -41,6 +42,22 @@ def _scenario(kind, policy):
 
 
 CELLS = [(kind, p) for kind in ("dense", "sparse") for p in POLICIES]
+
+
+def _scatters_by_scope(hlo_text):
+    """The scatter ops of an HLO text, counted by the outermost ``mc_``
+    scope of their ``op_name`` (``loop`` where none)."""
+    out = {}
+    for line in hlo_text.splitlines():
+        if not re.search(r"\bscatter\(", line):
+            continue
+        m = re.search(r'op_name="([^"]*)"', line)
+        name = m.group(1) if m else ""
+        found = [(name.find(f"/{s}/"), s) for s in SCOPES
+                 if f"/{s}/" in name]
+        scope = min(found)[1] if found else "loop"
+        out[scope] = out.get(scope, 0) + 1
+    return out
 
 
 def _events(trace_dir):
@@ -133,6 +150,60 @@ def test_run_writes_pack_launch_rebuild_spans(traced):
     for name, st in events:
         if name in ("mc.pack", "mc.launch"):
             assert (st["C"], st["N"], st["B"]) == (C, 64, len(CELLS))
+
+
+@pytest.mark.parametrize("form", ["dense", "scatter"])
+def test_launch_span_names_the_commit_form(traced, tmp_path, monkeypatch,
+                                           form):
+    """``mc.launch`` carries the stat ``commit``, the form of the window
+    commit in the program it ran: the (2, 64) bucket's own dense
+    select, or the scatters, forced by a zero slot limit."""
+    import jax
+    from repro.mc import kernels
+    from repro.mc.engine import run_scenarios
+    if form == "dense":
+        _, events = traced
+        B = len(CELLS)
+    else:
+        monkeypatch.setattr(kernels, "_DENSE_COMMIT_MAX_SLOTS", 0)
+        monkeypatch.setattr(kernels, "_GRID_CACHE", {})
+        run_scenarios([_scenario("dense", "cfs")])   # compiles
+        with jax.profiler.trace(str(tmp_path)):
+            run_scenarios([_scenario("dense", "cfs")])
+        events = _events(str(tmp_path))
+        B = 1
+    assert [st["commit"] for name, st in events
+            if name == "mc.launch"] == [form]
+    with jax.enable_x64(True):
+        text = kernels.grid_kernel(C, 64).lower(
+            *kernels._RUN_ARGS[(C, 64, B)]).compile().as_text()
+    assert ("mc_window_ff" in _scatters_by_scope(text)) == \
+        (form == "scatter")
+
+
+@pytest.mark.parametrize("n_slots", [64, 128, 256])
+def test_window_commit_holds_no_scatter(n_slots):
+    """The EP3 node's buckets (4 cores, 64 and 128 slots, window
+    W = 64) and a deep one (256 slots, W = 256) commit the window by
+    the dense select: their compiled programs hold no scatter in
+    ``mc_window_ff``, while the layers that keep theirs show them.
+    Buckets of more than 2,048 slots, the paper host's one-minute trace
+    (8,192) among them, keep the scatters; none is compiled here."""
+    import jax
+    import jax.numpy as jnp
+    from repro.mc.kernels import grid_kernel, window_commit
+    assert window_commit(n_slots) == "dense"
+    assert window_commit(2048) == "dense"
+    assert window_commit(4096) == window_commit(8192) == "scatter"
+    f64 = jax.ShapeDtypeStruct((2, n_slots), jnp.float64)
+    i32 = jax.ShapeDtypeStruct((2,), jnp.int32)
+    with jax.enable_x64(True):
+        text = grid_kernel(4, n_slots).lower(
+            f64, f64, i32, i32, jax.ShapeDtypeStruct((2,), jnp.float64)
+        ).compile().as_text()
+    scatters = _scatters_by_scope(text)
+    assert "mc_window_ff" not in scatters
+    assert scatters.get("mc_cycle_ff", 0) > 0
 
 
 def test_gc_spans_wrap_a_collection(tmp_path):
