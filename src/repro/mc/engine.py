@@ -22,7 +22,7 @@ import numpy as np
 
 from .dispatch import (enable_compile_cache, reason_key, supported,
                        tasks_supported)
-from .spans import EVENT_LAYERS, gc_spans, span
+from .spans import EVENT_LAYERS, FIRE_LAYERS, gc_spans, span
 
 if TYPE_CHECKING:
     from ..scenario import Scenario, ScenarioResult
@@ -143,22 +143,36 @@ def run_scenarios(scenarios: Sequence["Scenario"],
     scales); otherwise each ``workload.build()`` runs here. Raises
     ``ValueError`` on out-of-regime scenarios — callers partition
     with ``dispatch.supported`` first.  Each result carries
-    ``mc_stats`` = ``{"iters", "events", "events_by_layer"}``, summed
-    over fleet units: kernel while-loop trips, scheduling events
-    retired, and those events split by the layer that retired them
-    (a dict keyed by ``spans.EVENT_LAYERS``: ``cycle_ff``,
-    ``window_ff``, ``advance``, ``micro``; its values add up to
-    ``events``) — the algorithmic multi-event win stays visible even
-    where 1-core wall-clock hides it.
+    ``mc_stats``, the kernel's counters (the algorithmic multi-event
+    win stays visible even where 1-core wall-clock hides it):
+
+    * ``iters``, ``events``: kernel while-loop trips and scheduling
+      events retired, summed over fleet units;
+    * ``events_by_layer``: those events split by the layer that retired
+      them (a dict keyed by ``spans.EVENT_LAYERS``: ``cycle_ff``,
+      ``window_ff``, ``advance``, ``micro``; its values add up to
+      ``events``), summed over fleet units;
+    * ``fires_by_layer``: the (trip, core) firings of the two
+      multi-event layers (keyed by ``spans.FIRE_LAYERS``: ``cycle_ff``,
+      ``window_ff``), summed over fleet units;
+    * ``rq_depth_max``: the deepest CFS runqueue at the end of a trip,
+      the largest over fleet units;
+    * ``window_len``: W, the chunks a firing scans
+      (``kernels.window_len``), the largest over the units' buckets.
+
+    ``fires_by_layer`` and ``rq_depth_max`` are left out where the grid
+    function returns only the observables and the events (a stand-in
+    for ``run_grid``).
 
     Under ``jax.profiler`` the call writes host spans (``spans.span``),
     each with the stat ``call``, this process's number of the call:
 
     * ``mc.pack`` (also ``C``, ``N``, ``B``): filling one program's
       ``[B, N]`` arrays;
-    * ``mc.launch`` (``C``, ``N``, ``B``, and ``commit``, the form of
-      the window fast-forward's commit, ``kernels.window_commit``):
-      ``run_grid``, from the copy to the device through the program to
+    * ``mc.launch`` (``C``, ``N``, ``B``; ``commit``, the form of the
+      window fast-forward's commit, ``kernels.window_commit``; and
+      ``window``, its length W, ``kernels.window_len``): ``run_grid``,
+      from the copy to the device through the program to
       ``device_get``;
     * ``mc.rebuild``: the ``Task`` writes of one program (``C``, ``N``,
       ``B``), and one more for building the results;
@@ -174,7 +188,7 @@ def _run(scenarios, prebuilt, call: int) -> list["ScenarioResult"]:
     """``run_scenarios``'s work; ``call`` is the spans' ``call`` stat."""
     from ..core.metrics import SimResult
     from ..scenario import ScenarioResult
-    from .kernels import run_grid, window_commit
+    from .kernels import run_grid, window_commit, window_len
 
     built = []
     for k, sc in enumerate(scenarios):
@@ -216,6 +230,10 @@ def _run(scenarios, prebuilt, call: int) -> list["ScenarioResult"]:
     iters = [0] * len(scenarios)   # kernel while-loop trips per cell
     events = [0] * len(scenarios)  # scheduling events retired per cell
     by_layer = np.zeros((len(scenarios), len(EVENT_LAYERS)), np.int64)
+    fires = np.zeros((len(scenarios), len(FIRE_LAYERS)), np.int64)
+    depth = [0] * len(scenarios)   # deepest runqueue per cell
+    wlen = [0] * len(scenarios)    # window length per cell
+    counted = True                 # the grid counts firings and depth
     for (C, N), us in groups.items():
         B = len(us)
         with span("pack", call=call, C=C, N=N, B=B):
@@ -232,9 +250,10 @@ def _run(scenarios, prebuilt, call: int) -> list["ScenarioResult"]:
                 n_tasks[b] = len(idxs)
                 n_fifo[b], limit[b] = cell_params(scenarios[k])
         with span("launch", call=call, C=C, N=N, B=B,
-                  commit=window_commit(N)):
+                  commit=window_commit(N), window=window_len(N)):
             out = run_grid(arrival, service, n_tasks, n_fifo, limit,
                            n_cores=C)
+        counted = counted and "fires_by_layer" in out
         if not bool(np.all(out["ok"])):
             bad = sorted({units[us[b]][0] for b in range(B)
                           if not out["ok"][b]})
@@ -258,6 +277,10 @@ def _run(scenarios, prebuilt, call: int) -> list["ScenarioResult"]:
                 iters[k] += int(out["n_iters"][b])
                 events[k] += int(out["n_events"][b])
                 by_layer[k] += out["events_by_layer"][b]
+                wlen[k] = max(wlen[k], window_len(N))
+                if counted:
+                    fires[k] += out["fires_by_layer"][b]
+                    depth[k] = max(depth[k], int(out["rq_depth_max"][b]))
 
     results: list["ScenarioResult"] = []
     with span("rebuild", call=call):
@@ -269,10 +292,15 @@ def _run(scenarios, prebuilt, call: int) -> list["ScenarioResult"]:
                 raw = SimResult(
                     policy=sc.policy.name, tasks=tasks,
                     total_ctx=sum(t.ctx_switches for t in tasks))
+            stats = {"iters": iters[k], "events": events[k],
+                     "events_by_layer": dict(zip(
+                         EVENT_LAYERS, map(int, by_layer[k]))),
+                     "window_len": wlen[k]}
+            if counted:
+                stats["fires_by_layer"] = dict(zip(
+                    FIRE_LAYERS, map(int, fires[k])))
+                stats["rq_depth_max"] = depth[k]
             results.append(ScenarioResult(
-                scenario=sc, raw=raw, meta=dict(meta),
-                mc_stats={"iters": iters[k], "events": events[k],
-                          "events_by_layer": dict(zip(
-                              EVENT_LAYERS, map(int, by_layer[k])))},
+                scenario=sc, raw=raw, meta=dict(meta), mc_stats=stats,
                 backend="jax"))
     return results

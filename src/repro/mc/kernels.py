@@ -83,7 +83,7 @@ from repro.core.events import (_EPS, cfs_slice_ms, chunk_completes,
                                chunk_end_ms, chunk_run_ms,
                                fifo_budget_ms)
 
-from .spans import EVENT_LAYERS, SCOPES
+from .spans import EVENT_LAYERS, FIRE_LAYERS, SCOPES
 
 # Default Linux knobs of the supported regime (see module docstring);
 # the dispatch gate (repro.mc.dispatch) refuses cells that override
@@ -168,6 +168,12 @@ _MICRO_STEPS = 8
 _DENSE_COMMIT_MAX_SLOTS = 2048
 
 
+def window_len(n_slots: int) -> int:
+    """W, the chunks the cycle and window fast-forwards of an N-slot
+    bucket scan per core and trip: a window firing retires at most W."""
+    return _WINDOW_SMALL if n_slots <= 128 else _WINDOW_DEEP
+
+
 def window_commit(n_slots: int) -> str:
     """How the window fast-forward of an N-slot bucket commits the
     chunks it retires into the per-task state: ``"dense"``, one select
@@ -202,7 +208,7 @@ def make_cell_kernel(n_cores: int, n_slots: int):
     C, N = n_cores, n_slots
     LAT, GRAN, CTX = SCHED_LATENCY_MS, MIN_GRANULARITY_MS, CTX_SWITCH_MS
     KC = _CYCLE_K
-    W = _WINDOW_SMALL if N <= 128 else _WINDOW_DEEP
+    W = window_len(N)
     commit = window_commit(N)
 
     cids = jnp.arange(C, dtype=jnp.int32)
@@ -255,9 +261,13 @@ def make_cell_kernel(n_cores: int, n_slots: int):
             rrc=jnp.int32(0),
             it=jnp.int32(0),
             ev=jnp.int32(0),
-            # events retired per layer, in EVENT_LAYERS order; written
-            # by the outer loop only, never read back into the state
+            # counters written by the outer loop only, never read back
+            # into the state: events retired per layer (EVENT_LAYERS
+            # order), (trip, core) firings per multi-event layer
+            # (FIRE_LAYERS order), the deepest runqueue at a trip's end
             evl=jnp.zeros(len(EVENT_LAYERS), jnp.int32),
+            fires=jnp.zeros(len(FIRE_LAYERS), jnp.int32),
+            rqmax=jnp.int32(0),
         )
 
         def t_arr(st):
@@ -849,7 +859,8 @@ def make_cell_kernel(n_cores: int, n_slots: int):
         rotation_s, cycle_s, window_s, advance_s, micro_s = SCOPES
 
         def body(st):
-            evl, ev0 = st["evl"], st["ev"]
+            evl, fires, rqmax = st["evl"], st["fires"], st["rqmax"]
+            ev0 = st["ev"]
             st = dict(st, it=st["it"] + 1)
             ta = t_arr(st)
             tf, fcid, _ = fifo_candidate(st)
@@ -881,8 +892,10 @@ def make_cell_kernel(n_cores: int, n_slots: int):
                 stn = _sel_tree(
                     any_cfs, st_cfs,
                     _sel_tree(do_f, st_fifo, _sel_tree(do_a, st_arr, st)))
-            # the window's events count only where its state is selected
+            # the window's events and firings count only where its
+            # state is selected
             ev_w = jnp.where(any_cfs, st_w["ev"] - ev1, 0)
+            fire_w = jnp.where(any_cfs, jnp.sum(handled, dtype=jnp.int32), 0)
             ev2 = stn["ev"]
 
             # Micro-step chain: the sparse phases (arrival interleave,
@@ -894,8 +907,13 @@ def make_cell_kernel(n_cores: int, n_slots: int):
             with jax.named_scope(micro_s):
                 stn = lax.fori_loop(0, _MICRO_STEPS,
                                     lambda _, s: micro(s), stn)
-            return dict(stn, evl=evl + jnp.stack(
-                [ev1 - ev0, ev_w, ev2 - ev1 - ev_w, stn["ev"] - ev2]))
+            return dict(
+                stn,
+                evl=evl + jnp.stack(
+                    [ev1 - ev0, ev_w, ev2 - ev1 - ev_w, stn["ev"] - ev2]),
+                fires=fires + jnp.stack(
+                    [jnp.sum(cdid, dtype=jnp.int32), fire_w]),
+                rqmax=jnp.maximum(rqmax, jnp.max(stn["rqn"])))
 
         out = lax.while_loop(cond, body, st)
         live = jnp.arange(N) < n_tasks
@@ -905,7 +923,8 @@ def make_cell_kernel(n_cores: int, n_slots: int):
                     preemptions=out["npre"], ctx_switches=out["nctx"],
                     migrations=out["nmig"], cpu_time=out["cpu"],
                     ok=ok, n_iters=out["it"], n_events=out["ev"],
-                    events_by_layer=out["evl"])
+                    events_by_layer=out["evl"], fires_by_layer=out["fires"],
+                    rq_depth_max=out["rqmax"])
 
     return kernel
 
@@ -963,9 +982,11 @@ def run_grid(arrival, service, n_tasks, n_fifo, limit, *, n_cores: int):
     limit            : f64[B]
 
     Returns a dict of [B, N] observable arrays (see ``make_cell_kernel``)
-    and the per-cell counters ``ok``, ``n_iters``, ``n_events`` [B] and
-    ``events_by_layer`` [B, 4] (``spans.EVENT_LAYERS`` order), as NumPy,
-    computed under x64 on whatever backend JAX selected.
+    and the per-cell counters ``ok``, ``n_iters``, ``n_events``,
+    ``rq_depth_max`` [B], ``events_by_layer`` [B, 4]
+    (``spans.EVENT_LAYERS`` order) and ``fires_by_layer`` [B, 2]
+    (``spans.FIRE_LAYERS`` order), as NumPy, computed under x64 on
+    whatever backend JAX selected.
     """
     B, n_slots = arrival.shape
     with jax.enable_x64(True):

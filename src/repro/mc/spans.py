@@ -12,8 +12,11 @@ device spend their time:
   wraps around each retirement layer of its while-loop body; an op's
   layer is the outermost ``mc_`` segment of its HLO ``op_name``.
 
-The kernel also counts the events each retirement layer retires, in the
-order of :data:`EVENT_LAYERS`.
+The kernel also counts, per cell, the events each retirement layer
+retires (``events_by_layer``, in the order of :data:`EVENT_LAYERS`), the
+(trip, core) firings of each multi-event layer (``fires_by_layer``, in
+the order of :data:`FIRE_LAYERS`) and the deepest CFS runqueue at the
+end of a trip (``rq_depth_max``).
 
 Outside a profiler session a span costs one small object.
 """
@@ -28,6 +31,7 @@ SPAN_PREFIX = "mc."
 SCOPES = ("mc_rotation", "mc_cycle_ff", "mc_window_ff", "mc_advance",
           "mc_micro")
 EVENT_LAYERS = ("cycle_ff", "window_ff", "advance", "micro")
+FIRE_LAYERS = ("cycle_ff", "window_ff")
 
 
 def span(name: str, **ids):

@@ -65,6 +65,19 @@ def small_workload():
     return [t for t in w.tasks if t.arrival < 30_000]
 
 
+def assert_fires_bound_events(res):
+    """A batched result's firing counters against its event counters:
+    each (trip, core) firing of a multi-event layer retires at least one
+    event and at most W (``window_len``) of them, so per layer
+    ``fires <= events <= fires * W``: no firing, no event."""
+    from repro.mc.spans import FIRE_LAYERS
+    st = res.mc_stats
+    for layer in FIRE_LAYERS:
+        fires = st["fires_by_layer"][layer]
+        events = st["events_by_layer"][layer]
+        assert fires <= events <= fires * st["window_len"], (layer, st)
+
+
 def mk_tasks(specs):
     """specs: list of (arrival, service[, mem]) tuples."""
     out = []

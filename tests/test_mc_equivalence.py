@@ -16,7 +16,7 @@ from dataclasses import replace
 import pytest
 
 import repro
-from conftest import mk_tasks
+from conftest import assert_fires_bound_events, mk_tasks
 from repro import FleetSpec, PolicySpec, Scenario, WorkloadSpec
 from repro.mc import MonteCarlo, supported
 from repro.mc.dispatch import tasks_supported
@@ -309,6 +309,38 @@ def test_window_commit_forms_are_bit_identical(commit_forms, form):
 def test_deep_window_commit_forms_are_bit_identical(commit_forms, form):
     """The (2, 256) battery, window W = 256."""
     _assert_forms_agree(commit_forms, form, deep=True)
+
+
+@pytest.mark.parametrize("deep", [False, True], ids=["w64", "w256"])
+def test_battery_counters(commit_forms, deep):
+    """The kernel's diagnostic counters on each battery: firings bound
+    the events of their layer; the fifo cells keep no runqueue and fire
+    neither fast-forward; the first cfs cell's runqueue grows past the
+    cycle engine's members and the window fires."""
+    from repro.mc import kernels
+    results = commit_forms["dense"][deep]
+    W = 256 if deep else 64
+    for sc, r in zip(_commit_battery(deep), results):
+        assert r.mc_stats["window_len"] == W
+        assert_fires_bound_events(r)
+        if sc.policy.name == "fifo":
+            assert r.mc_stats["rq_depth_max"] == 0
+            assert r.mc_stats["fires_by_layer"] == {"cycle_ff": 0,
+                                                    "window_ff": 0}
+    cfs = results[1].mc_stats
+    assert cfs["rq_depth_max"] > kernels._CYCLE_K
+    assert cfs["fires_by_layer"]["window_ff"] > 0
+
+
+@pytest.mark.parametrize("deep", [False, True], ids=["w64", "w256"])
+def test_counters_alone_match_the_batch(commit_forms, deep):
+    """A cell's counters (and observables) are its own: run alone, at
+    B = 1, it reads what it read inside the battery's batch."""
+    from repro.mc.engine import run_scenarios
+    for sc, r in zip(_commit_battery(deep), commit_forms["dense"][deep]):
+        alone, = run_scenarios([sc])
+        assert alone.mc_stats == r.mc_stats
+        assert digest(alone) == digest(r)
 
 
 @pytest.mark.slow

@@ -3,7 +3,9 @@
 One small grid (C=2, N=64: one program) runs once under
 ``jax.profiler`` on the CPU; the tests read its per-layer event
 counters, the scopes in the executed program's HLO, and the host spans
-in the trace. The instrumentation must not change a single observable.
+in the trace. A cut of the paper's 50-core host (C=50, N=256: the deep
+window) checks the firing and runqueue counters where many cores fire.
+The instrumentation must not change a single observable.
 """
 import gc
 import glob
@@ -14,9 +16,10 @@ import numpy as np
 import pytest
 
 import repro
-from conftest import mk_tasks
+from conftest import assert_fires_bound_events, mk_tasks
 from repro import FleetSpec, PolicySpec, Scenario, WorkloadSpec
-from repro.mc.spans import EVENT_LAYERS, SCOPES, gc_spans
+from repro.mc.spans import EVENT_LAYERS, FIRE_LAYERS, SCOPES, gc_spans
+from repro.traces import TraceSpec, generate_workload
 
 C = 2
 POLICIES = {"fifo": {}, "cfs": {}, "hybrid": {"n_fifo": 1}}
@@ -150,6 +153,8 @@ def test_run_writes_pack_launch_rebuild_spans(traced):
     for name, st in events:
         if name in ("mc.pack", "mc.launch"):
             assert (st["C"], st["N"], st["B"]) == (C, 64, len(CELLS))
+        if name == "mc.launch":
+            assert st["window"] == 64
 
 
 @pytest.mark.parametrize("form", ["dense", "scatter"])
@@ -191,8 +196,9 @@ def test_window_commit_holds_no_scatter(n_slots):
     (8,192) among them, keep the scatters; none is compiled here."""
     import jax
     import jax.numpy as jnp
-    from repro.mc.kernels import grid_kernel, window_commit
+    from repro.mc.kernels import grid_kernel, window_commit, window_len
     assert window_commit(n_slots) == "dense"
+    assert window_len(n_slots) == (64 if n_slots <= 128 else 256)
     assert window_commit(2048) == "dense"
     assert window_commit(4096) == window_commit(8192) == "scatter"
     f64 = jax.ShapeDtypeStruct((2, n_slots), jnp.float64)
@@ -218,3 +224,71 @@ def test_gc_spans_wrap_a_collection(tmp_path):
     gen2 = [st for name, st in _events(str(tmp_path))
             if name == "mc.gc" and st["generation"] == 2]
     assert gen2 == [{"generation": 2}]
+
+
+# -- the paper's host: 50 cores, a cut the CPU runs in seconds --------------
+
+PAPER_POLICIES = {"fifo": {}, "cfs": {},
+                  "hybrid": {"n_fifo": 25, "time_limit_ms": 1633.0}}
+
+
+def _paper_scenario(policy):
+    """The paper host (50 cores, the paper's trace rates, its static
+    hybrid) on the invocations of the trace's first 0.15 s: 245 tasks,
+    one (50, 256) bucket, so the window holds W = 256 chunks."""
+    tasks = [t for t in generate_workload(TraceSpec(seed=0)).tasks
+             if t.arrival < 150.0]
+    return Scenario(workload=WorkloadSpec(kind="tasks", tasks=tasks),
+                    fleet=FleetSpec(cores_per_node=50),
+                    policy=PolicySpec(name=policy,
+                                      kw=PAPER_POLICIES[policy]))
+
+
+@pytest.fixture(scope="module")
+def paper():
+    """The three paper-host cells as one batch, by policy."""
+    from repro.mc.engine import run_scenarios
+    return dict(zip(PAPER_POLICIES, run_scenarios(
+        [_paper_scenario(p) for p in PAPER_POLICIES])))
+
+
+@pytest.mark.parametrize("policy", list(PAPER_POLICIES))
+def test_paper_host_fires_bound_events(paper, policy):
+    stats = paper[policy].mc_stats
+    assert stats["window_len"] == 256
+    assert list(stats["fires_by_layer"]) == list(FIRE_LAYERS)
+    assert_fires_bound_events(paper[policy])
+
+
+def test_paper_host_counts_runqueues_by_policy(paper):
+    """fifo keeps no runqueue and fires neither fast-forward; cfs spreads
+    the burst over 50 runqueues and the cycle engine fires; the hybrid's
+    25 CFS cores fill only by the FIFO cores' hand-off."""
+    fifo, cfs, hybrid = (paper[p] for p in PAPER_POLICIES)
+    assert fifo.mc_stats["rq_depth_max"] == 0
+    assert fifo.mc_stats["fires_by_layer"] == {"cycle_ff": 0,
+                                               "window_ff": 0}
+    assert cfs.mc_stats["rq_depth_max"] > 0
+    assert cfs.mc_stats["fires_by_layer"]["cycle_ff"] > 0
+    assert hybrid.mc_stats["rq_depth_max"] > 0
+    assert sum(t.migrations for t in hybrid.raw.tasks) > 0
+
+
+def test_paper_host_cell_alone_matches_the_batch(paper):
+    """The cfs cell run alone (B = 1) reads the counters it read in the
+    batch of three."""
+    from repro.mc.engine import run_scenarios
+    alone, = run_scenarios([_paper_scenario("cfs")])
+    assert alone.mc_stats == paper["cfs"].mc_stats
+
+
+@pytest.mark.parametrize("policy", list(PAPER_POLICIES))
+def test_paper_host_is_bit_identical(paper, policy):
+    scalar = repro.run(_paper_scenario(policy))
+
+    def digest(res):
+        return sorted((t.tid, repr(t.completion), repr(t.first_run),
+                       repr(t.cpu_time), t.preemptions, t.ctx_switches,
+                       t.migrations) for t in res.raw.tasks)
+
+    assert digest(paper[policy]) == digest(scalar)
