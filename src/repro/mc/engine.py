@@ -157,12 +157,15 @@ def run_scenarios(scenarios: Sequence["Scenario"],
       ``window_ff``), summed over fleet units;
     * ``rq_depth_max``: the deepest CFS runqueue at the end of a trip,
       the largest over fleet units;
+    * ``rotation_deep_trips``: the trips that began with a CFS runqueue
+      deeper than the rotation's pop prefix (``kernels._CYCLE_K - 1``),
+      summed over fleet units;
     * ``window_len``: W, the chunks a firing scans
       (``kernels.window_len``), the largest over the units' buckets.
 
-    ``fires_by_layer`` and ``rq_depth_max`` are left out where the grid
-    function returns only the observables and the events (a stand-in
-    for ``run_grid``).
+    ``fires_by_layer``, ``rq_depth_max`` and ``rotation_deep_trips``
+    are left out where the grid function returns only the observables
+    and the events (a stand-in for ``run_grid``).
 
     Under ``jax.profiler`` the call writes host spans (``spans.span``),
     each with the stat ``call``, this process's number of the call:
@@ -232,6 +235,7 @@ def _run(scenarios, prebuilt, call: int) -> list["ScenarioResult"]:
     by_layer = np.zeros((len(scenarios), len(EVENT_LAYERS)), np.int64)
     fires = np.zeros((len(scenarios), len(FIRE_LAYERS)), np.int64)
     depth = [0] * len(scenarios)   # deepest runqueue per cell
+    deep = [0] * len(scenarios)    # trips that began deep, per cell
     wlen = [0] * len(scenarios)    # window length per cell
     counted = True                 # the grid counts firings and depth
     for (C, N), us in groups.items():
@@ -281,6 +285,7 @@ def _run(scenarios, prebuilt, call: int) -> list["ScenarioResult"]:
                 if counted:
                     fires[k] += out["fires_by_layer"][b]
                     depth[k] = max(depth[k], int(out["rq_depth_max"][b]))
+                    deep[k] += int(out["rotation_deep_trips"][b])
 
     results: list["ScenarioResult"] = []
     with span("rebuild", call=call):
@@ -300,6 +305,7 @@ def _run(scenarios, prebuilt, call: int) -> list["ScenarioResult"]:
                 stats["fires_by_layer"] = dict(zip(
                     FIRE_LAYERS, map(int, fires[k])))
                 stats["rq_depth_max"] = depth[k]
+                stats["rotation_deep_trips"] = deep[k]
             results.append(ScenarioResult(
                 scenario=sc, raw=raw, meta=dict(meta), mc_stats=stats,
                 backend="jax"))

@@ -78,6 +78,47 @@ def assert_fires_bound_events(res):
         assert fires <= events <= fires * st["window_len"], (layer, st)
 
 
+def commit_battery(deep=False):
+    """fifo, cfs and hybrid on 2 cores, with fresh tasks on every call.
+    By default a dense and a sparse task set: one (C=2, N=64) program,
+    whose window fast-forward (W = 64) retires chunks on the dense cfs
+    cell. ``deep``: 150 tasks inside a second, one (2, 256) program
+    whose window holds W = 256 chunks."""
+    from repro import FleetSpec, PolicySpec, Scenario, WorkloadSpec
+    rng = np.random.default_rng(3)
+    if deep:
+        sets = {"deep": (rng.integers(0, 1000, 150),
+                         rng.integers(50, 400, 150))}
+    else:
+        sets = {"dense": (rng.integers(0, 500, 40),
+                          rng.integers(50, 400, 40)),
+                "sparse": (rng.integers(0, 2000, 12),
+                           rng.integers(1, 400, 12))}
+    kw = {"fifo": {}, "cfs": {}, "hybrid": {"n_fifo": 1}}
+    return [Scenario(workload=WorkloadSpec(kind="tasks", tasks=mk_tasks(
+                sorted(zip(arr.astype(float), svc.astype(float))))),
+                     fleet=FleetSpec(cores_per_node=2),
+                     policy=PolicySpec(name=policy, kw=kw[policy]))
+            for arr, svc in sets.values() for policy in kw]
+
+
+PAPER_POLICIES = {"fifo": {}, "cfs": {},
+                  "hybrid": {"n_fifo": 25, "time_limit_ms": 1633.0}}
+
+
+def paper_scenario(policy):
+    """The paper host (50 cores, the paper's trace rates, its static
+    hybrid) on the invocations of the trace's first 0.15 s: 245 tasks,
+    one (50, 256) bucket, so the window holds W = 256 chunks."""
+    from repro import FleetSpec, PolicySpec, Scenario, WorkloadSpec
+    tasks = [t for t in generate_workload(TraceSpec(seed=0)).tasks
+             if t.arrival < 150.0]
+    return Scenario(workload=WorkloadSpec(kind="tasks", tasks=tasks),
+                    fleet=FleetSpec(cores_per_node=50),
+                    policy=PolicySpec(name=policy,
+                                      kw=PAPER_POLICIES[policy]))
+
+
 def mk_tasks(specs):
     """specs: list of (arrival, service[, mem]) tuples."""
     out = []

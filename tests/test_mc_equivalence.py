@@ -16,7 +16,7 @@ from dataclasses import replace
 import pytest
 
 import repro
-from conftest import assert_fires_bound_events, mk_tasks
+from conftest import assert_fires_bound_events, commit_battery, mk_tasks
 from repro import FleetSpec, PolicySpec, Scenario, WorkloadSpec
 from repro.mc import MonteCarlo, supported
 from repro.mc.dispatch import tasks_supported
@@ -233,30 +233,6 @@ def test_vr_add_is_ieee_double_add(kind):
 
 # -- the window fast-forward's two commit forms (kernels.window_commit) -------
 
-def _commit_battery(deep=False):
-    """fifo, cfs and hybrid on 2 cores, with fresh tasks on every call.
-    By default a dense and a sparse task set: one (C=2, N=64) program,
-    whose window fast-forward (W = 64) retires chunks on the dense cfs
-    cell. ``deep``: 150 tasks inside a second, one (2, 256) program
-    whose window holds W = 256 chunks."""
-    import numpy as np
-    rng = np.random.default_rng(3)
-    if deep:
-        sets = {"deep": (rng.integers(0, 1000, 150),
-                         rng.integers(50, 400, 150))}
-    else:
-        sets = {"dense": (rng.integers(0, 500, 40),
-                          rng.integers(50, 400, 40)),
-                "sparse": (rng.integers(0, 2000, 12),
-                           rng.integers(1, 400, 12))}
-    kw = {"fifo": {}, "cfs": {}, "hybrid": {"n_fifo": 1}}
-    return [Scenario(workload=WorkloadSpec(kind="tasks", tasks=mk_tasks(
-                sorted(zip(arr.astype(float), svc.astype(float))))),
-                     fleet=FleetSpec(cores_per_node=2),
-                     policy=PolicySpec(name=policy, kw=kw[policy]))
-            for arr, svc in sets.values() for policy in kw]
-
-
 @pytest.fixture(scope="module")
 def commit_forms():
     """Both batteries' results under each commit form, by form and
@@ -273,7 +249,7 @@ def commit_forms():
             mp.setattr(kernels, "_GRID_CACHE", {})
             assert kernels.window_commit(64) == form
             assert kernels.window_commit(256) == form
-            out[form] = {deep: run_scenarios(_commit_battery(deep))
+            out[form] = {deep: run_scenarios(commit_battery(deep))
                          for deep in (False, True)}
     return out
 
@@ -291,7 +267,7 @@ def _assert_forms_agree(commit_forms, form, deep):
                        repr(t.cpu_time), t.preemptions, t.ctx_switches,
                        t.migrations) for t in res.raw.tasks)
 
-    for sc, got, alt in zip(_commit_battery(deep), got_all, other):
+    for sc, got, alt in zip(commit_battery(deep), got_all, other):
         scalar = repro.run(sc)
         assert digest_cpu(got) == digest_cpu(scalar)
         assert_same_summary(got, scalar)
@@ -320,7 +296,7 @@ def test_battery_counters(commit_forms, deep):
     from repro.mc import kernels
     results = commit_forms["dense"][deep]
     W = 256 if deep else 64
-    for sc, r in zip(_commit_battery(deep), results):
+    for sc, r in zip(commit_battery(deep), results):
         assert r.mc_stats["window_len"] == W
         assert_fires_bound_events(r)
         if sc.policy.name == "fifo":
@@ -337,7 +313,7 @@ def test_counters_alone_match_the_batch(commit_forms, deep):
     """A cell's counters (and observables) are its own: run alone, at
     B = 1, it reads what it read inside the battery's batch."""
     from repro.mc.engine import run_scenarios
-    for sc, r in zip(_commit_battery(deep), commit_forms["dense"][deep]):
+    for sc, r in zip(commit_battery(deep), commit_forms["dense"][deep]):
         alone, = run_scenarios([sc])
         assert alone.mc_stats == r.mc_stats
         assert digest(alone) == digest(r)

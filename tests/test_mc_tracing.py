@@ -16,10 +16,10 @@ import numpy as np
 import pytest
 
 import repro
-from conftest import assert_fires_bound_events, mk_tasks
+from conftest import (PAPER_POLICIES, assert_fires_bound_events, mk_tasks,
+                      paper_scenario)
 from repro import FleetSpec, PolicySpec, Scenario, WorkloadSpec
 from repro.mc.spans import EVENT_LAYERS, FIRE_LAYERS, SCOPES, gc_spans
-from repro.traces import TraceSpec, generate_workload
 
 C = 2
 POLICIES = {"fifo": {}, "cfs": {}, "hybrid": {"n_fifo": 1}}
@@ -228,28 +228,12 @@ def test_gc_spans_wrap_a_collection(tmp_path):
 
 # -- the paper's host: 50 cores, a cut the CPU runs in seconds --------------
 
-PAPER_POLICIES = {"fifo": {}, "cfs": {},
-                  "hybrid": {"n_fifo": 25, "time_limit_ms": 1633.0}}
-
-
-def _paper_scenario(policy):
-    """The paper host (50 cores, the paper's trace rates, its static
-    hybrid) on the invocations of the trace's first 0.15 s: 245 tasks,
-    one (50, 256) bucket, so the window holds W = 256 chunks."""
-    tasks = [t for t in generate_workload(TraceSpec(seed=0)).tasks
-             if t.arrival < 150.0]
-    return Scenario(workload=WorkloadSpec(kind="tasks", tasks=tasks),
-                    fleet=FleetSpec(cores_per_node=50),
-                    policy=PolicySpec(name=policy,
-                                      kw=PAPER_POLICIES[policy]))
-
-
 @pytest.fixture(scope="module")
 def paper():
     """The three paper-host cells as one batch, by policy."""
     from repro.mc.engine import run_scenarios
     return dict(zip(PAPER_POLICIES, run_scenarios(
-        [_paper_scenario(p) for p in PAPER_POLICIES])))
+        [paper_scenario(p) for p in PAPER_POLICIES])))
 
 
 @pytest.mark.parametrize("policy", list(PAPER_POLICIES))
@@ -278,13 +262,13 @@ def test_paper_host_cell_alone_matches_the_batch(paper):
     """The cfs cell run alone (B = 1) reads the counters it read in the
     batch of three."""
     from repro.mc.engine import run_scenarios
-    alone, = run_scenarios([_paper_scenario("cfs")])
+    alone, = run_scenarios([paper_scenario("cfs")])
     assert alone.mc_stats == paper["cfs"].mc_stats
 
 
 @pytest.mark.parametrize("policy", list(PAPER_POLICIES))
 def test_paper_host_is_bit_identical(paper, policy):
-    scalar = repro.run(_paper_scenario(policy))
+    scalar = repro.run(paper_scenario(policy))
 
     def digest(res):
         return sorted((t.tid, repr(t.completion), repr(t.first_run),

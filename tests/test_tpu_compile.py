@@ -50,6 +50,9 @@ def _spec(x, sharding):
 
 
 def test_mc_grid_program_compiles_for_v5e(one_chip):
+    """The smallest bucket: the rotation's batch-wide ``pmax`` and the
+    ``cond`` around the sort lower for the chip, and the conditional
+    survives as one."""
     from repro.mc.kernels import grid_kernel
     C, N, B = 4, 64, 3
     with jax.enable_x64(True):
@@ -60,6 +63,7 @@ def test_mc_grid_program_compiles_for_v5e(one_chip):
         compiled = grid_kernel(C, N).lower(
             f64(B, N), f64(B, N), i32(B), i32(B), f64(B)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes > 0
+    assert " conditional(" in compiled.as_text()
 
 
 @pytest.mark.parametrize("name", KERNELS)
