@@ -1,4 +1,4 @@
-"""Benchmark driver: one entry per paper table/figure + serving/roofline.
+"""Benchmark runner: one entry per paper table/figure + serving gateway.
 
 Prints ``name,us_per_call,derived`` CSV; full rows land in
 results/benchmarks/*.json.
@@ -29,7 +29,6 @@ BENCHES = [
     ("fig21_22_microvm", F.fig21_22_microvm),
     ("fig23_pareto", F.fig23_pareto),
     ("serving_gateway", S.serving_gateway),
-    ("roofline_table", S.roofline_table),
     ("cluster_matrix", C.cluster_matrix),
     ("resilience_matrix", R.resilience_matrix),
     ("topology_matrix", T.topology_matrix),

@@ -1,9 +1,6 @@
-"""Serving-gateway benchmarks: the paper's technique on model serving
-(per assigned arch) + roofline summary from the dry-run artifacts."""
+"""Serving-gateway benchmark: the paper's technique on model serving,
+per catalog architecture."""
 from __future__ import annotations
-
-import json
-from pathlib import Path
 
 from repro.configs import ARCHS, get_config
 from repro.scenario import (FleetSpec, PolicySpec, Scenario, ServingSpec,
@@ -50,28 +47,4 @@ def serving_gateway():
         })
     rows.sort(key=lambda r: -r["saving_vs_cfs"])
     rows.insert(0, {"arch": "best", "value": rows[0]["saving_vs_cfs"]})
-    return rows
-
-
-def roofline_table(results_dir: str = "results/dryrun"):
-    """Collate the dry-run artifacts into the Sec.-Roofline table."""
-    rows = []
-    for p in sorted(Path(results_dir).glob("*__single.json")):
-        d = json.loads(p.read_text())
-        if d.get("status") != "ok":
-            rows.append({"cell": p.stem, "status": d.get("status"),
-                         "reason": d.get("reason", "")[:60]})
-            continue
-        rows.append({
-            "cell": f'{d["arch"]}__{d["shape"]}',
-            "t_compute_s": round(d["t_compute"], 4),
-            "t_memory_s": round(d["t_memory"], 4),
-            "t_collective_s": round(d["t_collective"], 4),
-            "bottleneck": d["bottleneck"],
-            "useful_flops_ratio": (round(d["useful_flops_ratio"], 3)
-                                   if d.get("useful_flops_ratio") else None),
-            "mem_temp_gb": round((d.get("mem_temp_bytes") or 0) / 2**30, 2),
-        })
-    if not rows:
-        rows = [{"cell": "missing", "value": 0}]
     return rows

@@ -1,5 +1,7 @@
-"""repro — hybrid two-level FaaS scheduling (Zhao et al., 2024) as a
-production JAX training/serving framework. See DESIGN.md.
+"""repro — hybrid two-level FaaS scheduling (Zhao et al., 2024): a
+scalar discrete-event scheduler simulator, a batched Monte-Carlo engine
+that runs its sweeps on a TPU, fleets, and an LLM-as-FaaS workload
+priced through the scheduler. See DESIGN.md.
 
 The public entrypoint is the Scenario API::
 
@@ -9,8 +11,8 @@ The public entrypoint is the Scenario API::
     print(res.summary())
 
 Scenario machinery is imported lazily so that ``import repro`` stays
-dependency-free (the serving layer pulls in JAX only when a scenario
-actually needs it).
+dependency-free (the batched engine pulls in JAX only when a
+scenario actually needs it).
 """
 __version__ = "1.0.0"
 

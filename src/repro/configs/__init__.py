@@ -1,9 +1,5 @@
-"""repro.configs — model + shape + paper configurations."""
-from .base import (ModelConfig, SHAPES, ShapeConfig, TrainConfig,
-                   shape_applicable)
-from .registry import ARCHS, all_cells, get_config, get_smoke
+"""repro.configs — the published-model catalog."""
+from .base import ModelConfig
+from .registry import ARCHS, get_config
 
-__all__ = [
-    "ModelConfig", "SHAPES", "ShapeConfig", "TrainConfig",
-    "shape_applicable", "ARCHS", "all_cells", "get_config", "get_smoke",
-]
+__all__ = ["ModelConfig", "ARCHS", "get_config"]

@@ -8,10 +8,6 @@ CONFIG = ModelConfig(
     name="gemma3-12b", family="dense",
     n_layers=48, d_model=3840, n_heads=16, n_kv_heads=8,
     d_ff=15360, vocab=262144, local_window=1024, local_global_ratio=5,
-    tie_embeddings=True, rope_theta=1e6,
+    tie_embeddings=True,
     ms_per_token_decode=8.0, ms_per_ktoken_prefill=28.0,
 )
-
-def smoke() -> ModelConfig:
-    return CONFIG.with_(n_layers=7, d_model=64, n_heads=4, n_kv_heads=2,
-                        d_ff=128, vocab=256, local_window=16)

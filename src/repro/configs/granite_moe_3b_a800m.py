@@ -11,12 +11,6 @@ from .base import ModelConfig
 CONFIG = ModelConfig(
     name="granite-moe-3b-a800m", family="moe",
     n_layers=32, d_model=1536, n_heads=24, n_kv_heads=8,
-    d_ff=512, vocab=49155, n_experts=40, top_k=8,
-    tie_embeddings=True, rope_theta=1e4,
+    d_ff=512, vocab=49155, n_experts=40, top_k=8, tie_embeddings=True,
     ms_per_token_decode=3.0, ms_per_ktoken_prefill=9.0,
 )
-
-def smoke() -> ModelConfig:
-    return CONFIG.with_(n_layers=2, d_model=96, n_heads=6, n_kv_heads=2,
-                        d_ff=64, vocab=256, n_experts=4, top_k=2,
-                        capacity_factor=8.0)  # dropless for path-consistency tests

@@ -8,10 +8,6 @@ from .base import ModelConfig
 CONFIG = ModelConfig(
     name="musicgen-large", family="audio",
     n_layers=48, d_model=2048, n_heads=32, n_kv_heads=32,
-    d_ff=8192, vocab=2048, modality="audio", tie_embeddings=False,
+    d_ff=8192, vocab=2048, tie_embeddings=False,
     ms_per_token_decode=4.0, ms_per_ktoken_prefill=12.0,
 )
-
-def smoke() -> ModelConfig:
-    return CONFIG.with_(n_layers=2, d_model=64, n_heads=4, n_kv_heads=4,
-                        d_ff=128, vocab=128)

@@ -1,9 +1,9 @@
-"""Architecture registry: --arch <id> resolution + smoke variants."""
+"""Architecture registry: resolve a catalog name to its CONFIG."""
 from __future__ import annotations
 
 import importlib
 
-from .base import ModelConfig, SHAPES, ShapeConfig, shape_applicable
+from .base import ModelConfig
 
 ARCHS = (
     "granite-moe-3b-a800m",
@@ -26,19 +26,3 @@ def get_config(arch: str) -> ModelConfig:
         raise KeyError(f"unknown arch {arch!r}; have {list(ARCHS)}")
     mod = importlib.import_module(f"repro.configs.{_MODULES[arch]}")
     return mod.CONFIG
-
-
-def get_smoke(arch: str) -> ModelConfig:
-    mod = importlib.import_module(f"repro.configs.{_MODULES[arch]}")
-    return mod.smoke()
-
-
-def all_cells():
-    """All 40 (arch, shape) cells with applicability flags."""
-    out = []
-    for a in ARCHS:
-        cfg = get_config(a)
-        for s in SHAPES.values():
-            ok, why = shape_applicable(cfg, s)
-            out.append((a, s.name, ok, why))
-    return out

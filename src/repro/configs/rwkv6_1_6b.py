@@ -9,6 +9,3 @@ CONFIG = ModelConfig(
     d_ff=7168, vocab=65536, tie_embeddings=False,
     ms_per_token_decode=2.0, ms_per_ktoken_prefill=5.0,
 )
-
-def smoke() -> ModelConfig:
-    return CONFIG.with_(n_layers=2, d_model=64, d_ff=128, vocab=256)

@@ -11,8 +11,3 @@ CONFIG = ModelConfig(
     tie_embeddings=True,
     ms_per_token_decode=2.5, ms_per_ktoken_prefill=6.0,
 )
-
-def smoke() -> ModelConfig:
-    return CONFIG.with_(n_layers=8, d_model=64, n_heads=4, n_kv_heads=4,
-                        d_ff=128, vocab=256, ssm_state=16,
-                        shared_attn_every=3, ssm_chunk=16)

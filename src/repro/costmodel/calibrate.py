@@ -90,7 +90,7 @@ def measure_kernels(repeats: int = 5, small: bool = True) -> list[dict]:
 
     import jax
 
-    from ..launch.hlo_analysis import analyze
+    from .hlo_analysis import analyze
     from .features import _kernel_cases, compile_kernel
 
     interpret = jax.default_backend() != "tpu"

@@ -4,9 +4,8 @@ Two sources, one row schema:
 
 * :func:`kernel_features` — the REAL Pallas kernels (``kernels.ops``):
   each op is lowered and compiled via the standard jax path
-  (``jax.jit(...).lower(...).compile()``, the ``launch.dryrun`` idiom)
-  and its optimized HLO text is folded through
-  ``launch.hlo_analysis.analyze`` into FLOP / byte / trip-count
+  (``jax.jit(...).lower(...).compile()``) and its optimized HLO text
+  is folded through ``hlo_analysis.analyze`` into FLOP / byte / trip-count
   features. This is the compile side of compile-and-replay: the same
   compiled executable the calibrator later times.
 * :func:`llm_chunk_features` — the ``serving.llm`` chunk shapes,
@@ -143,7 +142,7 @@ def kernel_features(small: bool = True, ops_filter: Optional[list] = None,
     """
     import jax
 
-    from ..launch.hlo_analysis import analyze
+    from .hlo_analysis import analyze
 
     interpret = jax.default_backend() != "tpu"
     rows = []

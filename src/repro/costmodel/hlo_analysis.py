@@ -1,4 +1,4 @@
-"""Post-optimization HLO text analysis for the roofline report.
+"""Post-optimization HLO text analysis for the cost model's features.
 
 XLA's built-in ``compiled.cost_analysis()`` counts every while-loop body
 ONCE (verified in this container: a scanned 8-layer MLP reports 1/8th of

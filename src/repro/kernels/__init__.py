@@ -6,7 +6,7 @@ v5e VMEM.
 """
 from . import ref
 from .ops import (decode_attention, flash_attention, fused_rmsnorm,
-                  gqa_flash_attention, rwkv6_scan, ssm_scan)
+                  rwkv6_scan, ssm_scan)
 
 __all__ = ["ref", "decode_attention", "flash_attention", "fused_rmsnorm",
-           "gqa_flash_attention", "rwkv6_scan", "ssm_scan"]
+           "rwkv6_scan", "ssm_scan"]

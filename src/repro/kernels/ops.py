@@ -2,16 +2,13 @@
 
 Every wrapper lowers for the TPU unless the caller passes
 ``interpret=True`` (the Pallas interpreter, for CPU tests and CPU
-calibration); nothing picks interpret mode on its own. The wrappers
-also accept the model-layout GQA tensors and flatten them to kernel
-layout.
+calibration); nothing picks interpret mode on its own.
 """
 from __future__ import annotations
 
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
 from . import ref
 from .decode_attention import decode_attention as _decode
@@ -52,16 +49,3 @@ def rwkv6_scan(r, k, v, w, u, *, chunk=32, interpret=False):
 def fused_rmsnorm(x, w, *, eps=1e-6, rows=256, interpret=False):
     return _rmsnorm(x, w, eps=eps, rows=rows,
                     interpret=interpret)
-
-
-def gqa_flash_attention(q, k, v, **kw):
-    """Model-layout wrapper: q (B, KV, G, S, hd), k/v (B, KV, S, hd)."""
-    B, KV, G, S, hd = q.shape
-    qf = q.reshape(B * KV, G * S, hd) if G == 1 else \
-        q.transpose(0, 1, 2, 3, 4).reshape(B * KV * G, S, hd)
-    kf = jnp.repeat(k.reshape(B * KV, -1, hd), G, axis=0) if G > 1 \
-        else k.reshape(B * KV, -1, hd)
-    vf = jnp.repeat(v.reshape(B * KV, -1, hd), G, axis=0) if G > 1 \
-        else v.reshape(B * KV, -1, hd)
-    out = flash_attention(qf, kf, vf, **kw)
-    return out.reshape(B, KV, G, S, hd)

@@ -3,7 +3,7 @@
 Grid (batch*heads, n_chunks): the recurrent state h (hd, ds) persists in
 VMEM scratch across sequential chunk steps; within a chunk the
 intra-chunk term is the quadratic (Q,Q) decay-masked form (MXU work),
-matching repro.models.ssm.ssm_block chunk math exactly.
+matching the chunk math of ``ref.ssm_scan_ref`` exactly.
 """
 from __future__ import annotations
 

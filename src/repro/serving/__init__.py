@@ -1,9 +1,8 @@
-"""repro.serving — FaaS-for-models gateway + real-model engine."""
+"""repro.serving — FaaS-for-models gateway and the LLM-as-FaaS workload."""
 from .request import (RequestSpec, kv_bytes, preemption_penalty_ms,
                       service_ms)
 from .gateway import (GatewayResult, SlotCFS, SlotHybridScheduler,
                       requests_from_trace, run_gateway, run_gateway_fleet)
-from .engine import LiveRequest, ServingEngine
 from .llm import (LLMSpec, approx_param_bytes, llm_requests, llm_workload,
                   request_chunks)
 
@@ -11,7 +10,6 @@ __all__ = [
     "RequestSpec", "kv_bytes", "preemption_penalty_ms", "service_ms",
     "GatewayResult", "SlotCFS", "SlotHybridScheduler",
     "requests_from_trace", "run_gateway", "run_gateway_fleet",
-    "LiveRequest", "ServingEngine",
     "LLMSpec", "approx_param_bytes", "llm_requests", "llm_workload",
     "request_chunks",
 ]

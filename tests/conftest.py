@@ -41,13 +41,14 @@ def pytest_sessionfinish(session, exitstatus):
 def pytest_addoption(parser):
     parser.addoption(
         "--slow", action="store_true", default=False,
-        help="also run tests marked slow (JAX kernel/model tier)")
+        help="also run tests marked slow (Pallas kernels, MC hypothesis "
+             "sweeps)")
 
 
 def pytest_collection_modifyitems(config, items):
-    """Two-tier suite: the default tier must stay fast (<2 min) so it is
-    practical to run on every change; ``--slow`` opts into the JAX
-    kernel/model tier (CI runs it nightly)."""
+    """Two-tier suite: the default tier must stay fast (a few minutes) so
+    it is practical to run on every change; ``--slow`` opts into the
+    Pallas kernel tests and the MC hypothesis sweeps (CI runs it nightly)."""
     if config.getoption("--slow"):
         return
     skip = pytest.mark.skip(reason="slow tier: pass --slow to run")

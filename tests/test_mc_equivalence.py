@@ -5,11 +5,12 @@ reproduce the scalar engine's per-task observables and summary
 roll-ups BIT-FOR-BIT (x64 CPU), and everything out of regime must be
 refused by the static gate — never silently approximated.
 
-Fast tier: the gate itself, the python-backend front door, and the
+Fast tier: the gate itself, the python-backend front door, the
 compiled program on the smoke trace through both front doors
-(``run_scenarios``, ``MonteCarlo``). Slow tier (``--slow``): the
-golden battery across seeds x policies, sweep-backend parity, fleets,
-and a hypothesis sweep over randomized small task grids.
+(``run_scenarios``, ``MonteCarlo``), the golden battery across seeds x
+policies, sweep-backend parity and the fleet battery. Slow tier
+(``--slow``): the hypothesis sweeps over randomized small task grids,
+which draw new examples on every run.
 """
 from dataclasses import replace
 
@@ -319,14 +320,12 @@ def test_counters_alone_match_the_batch(commit_forms, deep):
         assert digest(alone) == digest(r)
 
 
-@pytest.mark.slow
 def test_hybrid_knobs_equivalence():
     assert_bit_identical(_scenario("hybrid", n_fifo=1))
     assert_bit_identical(_scenario("hybrid", n_fifo=3,
                                    time_limit_ms=400.0))
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("seed", [0, 1, 2])
 @pytest.mark.parametrize("policy", ["fifo", "cfs", "hybrid"])
 def test_golden_battery(policy, seed):
@@ -370,7 +369,6 @@ def test_sweep_mixed_grid_spawns_its_scalar_pool(monkeypatch):
         [strip_backend(r) for r in run_sweep(grid[::2], parallel=False)]
 
 
-@pytest.mark.slow
 def test_montecarlo_mixed_grid_falls_back_transparently():
     """A fleet-shaped scenario is out of regime: the jax backend must
     route it to the scalar engine, not refuse the whole grid."""
@@ -383,7 +381,6 @@ def test_montecarlo_mixed_grid_falls_back_transparently():
     assert out.rows[0]["n"] > 0
 
 
-@pytest.mark.slow
 def test_sweep_backend_parity():
     from repro.cluster.sweep import build_grid, run_sweep
     grid = build_grid(("fifo", "cfs", "hybrid"), ["none"], [1],
@@ -396,9 +393,8 @@ def test_sweep_backend_parity():
         [strip_backend(r) for r in py]
 
 
-# -- slow tier: the newly-admitted fleet class (ISSUE 9) -----------------------
+# -- flat replayable fleets ----------------------------------------------------
 
-@pytest.mark.slow
 @pytest.mark.parametrize("dispatcher", ["round_robin", "random"])
 @pytest.mark.parametrize("policy", ["fifo", "cfs", "hybrid"])
 def test_fleet_golden_battery(policy, dispatcher):
@@ -421,7 +417,6 @@ def test_fleet_golden_battery(policy, dispatcher):
     assert batched.raw.node_meta == scalar.raw.node_meta
 
 
-@pytest.mark.slow
 def test_montecarlo_fleet_cells_ride_the_device():
     sc = _fleet_scenario("hybrid", "round_robin")
     kw = dict(seeds=(0, 1), loads=(1.0,))

@@ -1,14 +1,9 @@
-"""Serving gateway + real-model engine."""
-import jax
-import jax.numpy as jnp
+"""Serving gateway and the per-family request model."""
 import pytest
 
-from repro.configs import get_config, get_smoke
-from repro.distributed import materialize
-from repro.models import model_specs
-from repro.serving import (LiveRequest, ServingEngine, kv_bytes,
-                           preemption_penalty_ms, requests_from_trace,
-                           run_gateway)
+from repro.configs import get_config
+from repro.serving import (kv_bytes, preemption_penalty_ms,
+                           requests_from_trace, run_gateway)
 from repro.traces import TraceSpec
 
 SMALL = TraceSpec(minutes=1, invocations_per_min=6000, n_functions=60,
@@ -61,25 +56,3 @@ def test_gateway_straggler_redispatch(gw_requests):
     r = run_gateway(cfg, "hybrid", requests=gw_requests,
                     straggler_factor=3.0)
     assert r.redispatches >= 0          # hook wired (count depends on load)
-
-
-def test_engine_end_to_end():
-    cfg = get_smoke("qwen2-vl-2b")
-    params = materialize(model_specs(cfg), jax.random.PRNGKey(0))
-    eng = ServingEngine(cfg, params, n_slots=3, n_fifo=2, max_len=48,
-                        initial_limit_ms=25.0)
-    key = jax.random.PRNGKey(1)
-    for rid in range(5):
-        toks = jax.random.randint(jax.random.fold_in(key, rid), (1, 6),
-                                  0, cfg.vocab)
-        eng.submit(LiveRequest(rid=rid, arrival_ms=0.0, tokens=toks,
-                               max_new=3 + rid * 3))
-    done = eng.run()
-    assert len(done) == 5
-    for r in done:
-        assert len(r.generated) == 3 + r.rid * 3
-        assert r.completion_ms > 0 and r.cost_usd() > 0
-    # the long requests should have been preempted out of FIFO slots
-    assert any(r.preemptions > 0 for r in done)
-    # adapter learned from completions
-    assert len(eng.adapter.window) == 5

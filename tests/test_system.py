@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.core import run_policy
-from repro.launch.hlo_analysis import analyze, shape_bytes
+from repro.costmodel.hlo_analysis import analyze, shape_bytes
 
 
 @pytest.fixture(scope="module")
