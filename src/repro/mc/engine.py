@@ -160,12 +160,17 @@ def run_scenarios(scenarios: Sequence["Scenario"],
     * ``rotation_deep_trips``: the trips that began with a CFS runqueue
       deeper than the rotation's pop prefix (``kernels._CYCLE_K - 1``),
       summed over fleet units;
+    * ``window_trips``: the trips on which the cell's batch ran the
+      window fast-forward, which it runs wherever some core of some
+      cell retires a chunk by it, summed over fleet units. Unlike the
+      others this counter is the batch's: alone a cell counts the trips
+      on which it fires itself, and in a batch at least those;
     * ``window_len``: W, the chunks a firing scans
       (``kernels.window_len``), the largest over the units' buckets.
 
-    ``fires_by_layer``, ``rq_depth_max`` and ``rotation_deep_trips``
-    are left out where the grid function returns only the observables
-    and the events (a stand-in for ``run_grid``).
+    ``fires_by_layer``, ``rq_depth_max``, ``rotation_deep_trips`` and
+    ``window_trips`` are left out where the grid function returns only
+    the observables and the events (a stand-in for ``run_grid``).
 
     Under ``jax.profiler`` the call writes host spans (``spans.span``),
     each with the stat ``call``, this process's number of the call:
@@ -236,6 +241,7 @@ def _run(scenarios, prebuilt, call: int) -> list["ScenarioResult"]:
     fires = np.zeros((len(scenarios), len(FIRE_LAYERS)), np.int64)
     depth = [0] * len(scenarios)   # deepest runqueue per cell
     deep = [0] * len(scenarios)    # trips that began deep, per cell
+    wtrips = [0] * len(scenarios)  # trips the batch ran the window
     wlen = [0] * len(scenarios)    # window length per cell
     counted = True                 # the grid counts firings and depth
     for (C, N), us in groups.items():
@@ -286,6 +292,7 @@ def _run(scenarios, prebuilt, call: int) -> list["ScenarioResult"]:
                     fires[k] += out["fires_by_layer"][b]
                     depth[k] = max(depth[k], int(out["rq_depth_max"][b]))
                     deep[k] += int(out["rotation_deep_trips"][b])
+                    wtrips[k] += int(out["window_trips"][b])
 
     results: list["ScenarioResult"] = []
     with span("rebuild", call=call):
@@ -306,6 +313,7 @@ def _run(scenarios, prebuilt, call: int) -> list["ScenarioResult"]:
                     FIRE_LAYERS, map(int, fires[k])))
                 stats["rq_depth_max"] = depth[k]
                 stats["rotation_deep_trips"] = deep[k]
+                stats["window_trips"] = wtrips[k]
             results.append(ScenarioResult(
                 scenario=sc, raw=raw, meta=dict(meta), mc_stats=stats,
                 backend="jax"))

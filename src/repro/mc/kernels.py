@@ -37,6 +37,8 @@ as fixed-length ``lax.scan`` batches:
   the chunk axis), completions retired inline, the surviving prefix
   committed by a dense ownership select (by scatter in buckets of
   more than 2,048 slots, where the select costs more: `window_commit`).
+  It runs only on the trips where it retires a chunk on some core of
+  some cell of the batch (`window_engine`'s ``head``).
 * **generic advance** — the original one-event expire+pick, kept as
   the universal fallback: any chunk the batches decline (unstable
   push, slice change, the completing chunk of a cycle) retires here
@@ -261,6 +263,237 @@ def _sel_tree(pred, new, old):
     return {k: jnp.where(pred, new[k], old[k]) for k in old}
 
 
+def before_barrier(e, ta, tf, fcid):
+    """Strictly-before-barrier test for CFS expiries at ``e``, [C] or
+    [C, W] with cores first (heap order: arrivals win ties, FIFO
+    expiries tie-break on core id)."""
+    cw = jnp.arange(e.shape[0], dtype=jnp.int32) < fcid
+    if e.ndim == 2:
+        cw = cw[:, None]
+    return (e < ta) & ((e < tf) | ((e == tf) & cw))
+
+
+def slice_bits(k):
+    """The slice ``cfs_slice_ms(k)`` as vr bits."""
+    return jnp.asarray(_SLICE_BITS)[jnp.clip(k, 0, _SLICE_KMAX)]
+
+
+def window_engine(n_cores: int, n_slots: int):
+    """The window fast-forward of a (C, N) bucket: ``(head,
+    window_ff)``, two functions of the kernel's state dict ``st``, the
+    CFS cores eligible for it ``elig`` (bool[C]), the barrier (``ta``,
+    ``tf``, ``fcid``) and the trip's pop order ``rot`` [C, N].
+
+    ``window_ff`` returns the state with the window's retirements
+    committed and bool[C], the cores it retired chunks on. ``head`` is
+    its chunk-0 test, bool[C]: the cores on which the window retires
+    the in-flight chunk. Each chunk retires only after every chunk
+    before it, so those are exactly the cores ``window_ff`` retires
+    chunks on; where ``head`` holds on no core, ``window_ff`` returns
+    ``st`` unchanged in every field.
+
+    The scalar engine's `_window_fast_forward` twin: evaluate ONE
+    rotation of a core's runqueue (up to W chunks) at once. Chunk 0 is
+    the in-flight chunk; chunk i >= 1 pops rotation[i - 1]. All masks
+    are vector predicates over the chunk axis; only the end-time chain
+    is sequential (explicit lax.scan left fold). Completions retire
+    inline; the integer cumsum of completion flags and the cummax of
+    push keys are the ONLY associative scans (both exact under
+    reassociation).
+    """
+    C, N = n_cores, n_slots
+    LAT, GRAN, CTX = SCHED_LATENCY_MS, MIN_GRANULARITY_MS, CTX_SWITCH_MS
+    W = window_len(N)
+    commit = window_commit(N)
+    cids = jnp.arange(C, dtype=jnp.int32)
+
+    def head(st, elig, ta, tf, fcid, rot):
+        """An eligible core with a queue retires its in-flight chunk
+        before the barrier where the chunk completes, or where its
+        pushback lands at or after the queue's deepest key, that of the
+        queue's last pop. O(C) gathers, none of the window's [C, W + 1]
+        arrays."""
+        k1 = st["rqn"]
+        cur = jnp.where(elig, st["cur"], 0)
+        tail = jnp.take_along_axis(
+            rot, jnp.maximum(k1 - 1, 0)[:, None], 1)[:, 0]
+        comp0 = chunk_completes(st["rem"][cur], st["clen"])
+        stab0 = vr_add(st["vr"][cur], st["cslb"]) >= st["vr"][tail]
+        return elig & (k1 >= 1) & \
+            before_barrier(st["end"], ta, tf, fcid) & (comp0 | stab0)
+
+    def window_ff(st, elig, ta, tf, fcid, rot):
+        k1 = st["rqn"]
+        winm = elig & (k1 >= 1)
+        cur = st["cur"]
+        ii = jnp.arange(W + 1, dtype=jnp.int32)[None, :]
+        u = jnp.concatenate([jnp.where(winm, cur, N)[:, None],
+                             rot[:, :W]], axis=1)       # [C, W+1]
+        uvalid = winm[:, None] & (ii <= k1[:, None])
+        u = jnp.where(uvalid, u, N)
+        su = jnp.minimum(u, N - 1)
+        rem0 = st["rem"][su]
+        vr0 = st["vr"][su]
+        fr0 = st["fr"][su]
+        s = cfs_slice_ms(k1, LAT, GRAN, _max=jnp.maximum)
+        runs = chunk_run_ms(rem0, s[:, None], _min=jnp.minimum,
+                            _max=jnp.maximum)
+        runs = jnp.where(ii == 0, st["clen"][:, None], runs)
+        comp = chunk_completes(rem0, runs)
+        cum = jnp.cumsum(comp.astype(jnp.int32), axis=1)
+        cumx = jnp.concatenate(
+            [jnp.zeros((C, 1), jnp.int32), cum[:, :-1]], axis=1)
+        # slice at chunk i's pick: queue holds k1 - (completions
+        # among chunks < i) entries after the pop.
+        s_i = cfs_slice_ms(k1[:, None] - cumx, LAT, GRAN,
+                           _max=jnp.maximum)
+        slice_ok = s_i == s[:, None]
+        pushed = vr_add(vr0, jnp.where(ii == 0, st["cslb"][:, None],
+                                       slice_bits(k1)[:, None]))
+        # Stability: a non-completing pushback must land at the
+        # tail — at/after the deepest original key and every
+        # earlier in-window push (exact cummax).
+        pkey = jnp.where(comp, _VR_BOT, pushed)
+        # Deepest original key: rotation is sorted, so the last
+        # queue entry (possibly beyond the window) carries it.
+        tail_tid = jnp.take_along_axis(
+            rot, jnp.maximum(k1 - 1, 0)[:, None], 1)[:, 0]
+        tail0 = st["vr"][tail_tid]
+        prior = jnp.concatenate(
+            [tail0[:, None],
+             jnp.maximum(tail0[:, None],
+                         lax.cummax(pkey, axis=1)[:, :-1])], axis=1)
+        stab = pushed >= prior
+
+        def estep(e, run_col):
+            e2 = chunk_end_ms(e, CTX, run_col)
+            return e2, e2
+
+        _, etail = lax.scan(estep, st["end"], runs[:, 1:].T,
+                            unroll=8)
+        E = jnp.concatenate([st["end"][:, None], etail.T], axis=1)
+        # Chunk 0 is `head`'s test; column W never holds, so the window
+        # retires c >= 1 chunks exactly where `head` holds.
+        ok = jnp.where(ii == 0, head(st, elig, ta, tf, fcid, rot)[:, None],
+                       uvalid & (ii < k1[:, None]) & (ii < W) &
+                       before_barrier(E, ta, tf, fcid) & slice_ok &
+                       (comp | stab))
+        c = jnp.argmax(~ok, axis=1).astype(jnp.int32)
+        did = c >= 1
+
+        cm = c[:, None]
+        Ec1 = jnp.take_along_axis(E, jnp.maximum(cm - 1, 0), 1)[:, 0]
+        cumc = jnp.take_along_axis(cumx, cm, 1)[:, 0]
+        s_c = jnp.take_along_axis(s_i, cm, 1)[:, 0]
+        rem_c = jnp.take_along_axis(rem0, cm, 1)[:, 0]
+        run_c = chunk_run_ms(rem_c, s_c, _min=jnp.minimum,
+                             _max=jnp.maximum)
+        end_c = chunk_end_ms(Ec1, CTX, run_c)
+        u_c = jnp.take_along_axis(u, cm, 1)[:, 0]
+        u_cp = jnp.take_along_axis(u, jnp.maximum(cm - 1, 0),
+                                   1)[:, 0]
+        vr0_c = jnp.take_along_axis(vr0, cm, 1)[:, 0]
+
+        R = winm[:, None] & (ii < cm)          # retired chunks
+        Rc = R & comp
+        Rp = R & ~comp
+        # picks: chunks 1..c start at the previous chunk's end;
+        # rotation members are pairwise distinct and distinct from
+        # the chunk-0 task, so every pick charges a ctx switch.
+        P = winm[:, None] & (ii >= 1) & (ii <= cm)
+        Pf = P & jnp.isnan(fr0)                # first runs
+        Eprev = jnp.concatenate([jnp.zeros((C, 1)), E[:, :-1]],
+                                axis=1)
+        pushseq = st["seqc"][:, None] + (ii - cumx)
+        rem_w = jnp.where(comp, 0.0, rem0 - runs)
+        qc = jnp.broadcast_to(cids[:, None], (C, W + 1))
+
+        if commit == "dense":
+            # Every valid entry of `u` is a distinct task (running
+            # on one core or queued on one), so the commit is a
+            # permutation write: each task reads its one update out
+            # of the window, selected by ownership and reduced over
+            # (core, chunk) against a neutral below every update.
+            tids = jnp.arange(N, dtype=jnp.int32)
+            own = u[:, :, None] == tids            # [C, W+1, N]
+
+            def hit(mask):
+                return jnp.any(own & mask[:, :, None], axis=(0, 1))
+
+            def read(mask, v):
+                low = (-_INF if jnp.issubdtype(v.dtype, jnp.floating)
+                       else jnp.iinfo(v.dtype).min)
+                return jnp.max(jnp.where(own & mask[:, :, None],
+                                         v[:, :, None], low),
+                               axis=(0, 1))
+
+            hR, hRc, hRp, hP, hPf = map(hit, (R, Rc, Rp, P, Pf))
+            running = jnp.any(did[:, None] & (u_c[:, None] == tids),
+                              axis=0)
+            new = dict(
+                rem=jnp.where(hR, read(R, rem_w), st["rem"]),
+                cpu=jnp.where(hR, st["cpu"] + read(R, runs),
+                              st["cpu"]),
+                comp=jnp.where(hRc, read(Rc, E), st["comp"]),
+                vr=jnp.where(hRp, read(Rp, pushed), st["vr"]),
+                npre=jnp.where(hRp, st["npre"] + 1, st["npre"]),
+                seq=jnp.where(hRp, read(Rp, pushseq), st["seq"]),
+                qcore=jnp.where(hRp, read(Rp, qc), st["qcore"]),
+                fr=jnp.where(hPf, read(Pf, Eprev), st["fr"]),
+                nctx=jnp.where(hP, st["nctx"] + 1, st["nctx"]),
+                stat=jnp.where(running, 3, jnp.where(
+                    hRc, 4, jnp.where(hRp, 2, st["stat"]))),
+            )
+        else:
+            tR = jnp.where(R, u, N).reshape(-1)
+            tRc = jnp.where(Rc, u, N).reshape(-1)
+            tRp = jnp.where(Rp, u, N).reshape(-1)
+            tfr = jnp.where(Pf, u, N).reshape(-1)
+            tP = jnp.where(P, u, N).reshape(-1)
+            new = dict(
+                rem=st["rem"].at[tR].set(rem_w.reshape(-1),
+                                         mode="drop"),
+                cpu=st["cpu"].at[tR].add(runs.reshape(-1),
+                                         mode="drop"),
+                comp=st["comp"].at[tRc].set(E.reshape(-1),
+                                            mode="drop"),
+                vr=st["vr"].at[tRp].set(pushed.reshape(-1),
+                                        mode="drop"),
+                npre=st["npre"].at[tRp].add(1, mode="drop"),
+                seq=st["seq"].at[tRp].set(pushseq.reshape(-1),
+                                          mode="drop"),
+                qcore=st["qcore"].at[tRp].set(qc.reshape(-1),
+                                              mode="drop"),
+                fr=st["fr"].at[tfr].set(Eprev.reshape(-1),
+                                        mode="drop"),
+                nctx=st["nctx"].at[tP].add(1, mode="drop"),
+                stat=st["stat"].at[tRp].set(2, mode="drop")
+                    .at[tRc].set(4, mode="drop")
+                    .at[jnp.where(did, u_c, N)].set(3, mode="drop"),
+            )
+
+        st2 = dict(
+            st,
+            **new,
+            cur=jnp.where(did, u_c, st["cur"]),
+            last=jnp.where(did, u_cp, st["last"]),
+            end=jnp.where(did, end_c, st["end"]),
+            clen=jnp.where(did, run_c, st["clen"]),
+            cslb=jnp.where(did, slice_bits(k1 - cumc), st["cslb"]),
+            # pops ratchet min_vruntime through nondecreasing keys:
+            # the iterated max equals one max against the last pop.
+            minvr=jnp.where(did,
+                            jnp.maximum(st["minvr"], vr0_c),
+                            st["minvr"]),
+            seqc=st["seqc"] + jnp.where(did, c - cumc, 0),
+            rqn=jnp.where(did, k1 - cumc, st["rqn"]),
+            ev=st["ev"] + jnp.sum(jnp.where(did, c, 0), dtype=jnp.int32),
+        )
+        return st2, did
+
+    return head, window_ff
+
+
 def make_cell_kernel(n_cores: int, n_slots: int):
     """Build the single-cell simulator for a static shape bucket.
 
@@ -276,14 +509,9 @@ def make_cell_kernel(n_cores: int, n_slots: int):
     LAT, GRAN, CTX = SCHED_LATENCY_MS, MIN_GRANULARITY_MS, CTX_SWITCH_MS
     KC = _CYCLE_K
     W = window_len(N)
-    commit = window_commit(N)
+    window_head, window_ff = window_engine(C, N)
 
     cids = jnp.arange(C, dtype=jnp.int32)
-    slice_bits_tab = jnp.asarray(_SLICE_BITS)
-
-    def slice_bits(k):
-        """The slice ``cfs_slice_ms(k)`` as vr bits."""
-        return slice_bits_tab[jnp.clip(k, 0, _SLICE_KMAX)]
 
     def kernel(arrival, service, n_tasks, n_fifo, limit):
         """Run one cell to completion.
@@ -332,11 +560,13 @@ def make_cell_kernel(n_cores: int, n_slots: int):
             # into the state: events retired per layer (EVENT_LAYERS
             # order), (trip, core) firings per multi-event layer
             # (FIRE_LAYERS order), the deepest runqueue at a trip's end,
-            # the trips that began with a runqueue deeper than KC - 1
+            # the trips that began with a runqueue deeper than KC - 1,
+            # the trips on which the batch ran the window
             evl=jnp.zeros(len(EVENT_LAYERS), jnp.int32),
             fires=jnp.zeros(len(FIRE_LAYERS), jnp.int32),
             rqmax=jnp.int32(0),
             rdeep=jnp.int32(0),
+            wtrips=jnp.int32(0),
         )
 
         def t_arr(st):
@@ -351,15 +581,6 @@ def make_cell_kernel(n_cores: int, n_slots: int):
             tmin = jnp.min(e)
             fcid = jnp.argmax(busy & (e == tmin)).astype(jnp.int32)
             return tmin, fcid, jnp.any(busy)
-
-        def bb(e, ta, tf, fcid):
-            """Strictly-before-barrier test for a CFS expiry at ``e``
-            (heap order: arrivals win ties, FIFO expiries tie-break on
-            core id). Accepts [C] or [C, W] expiry arrays."""
-            cw = cids < fcid
-            if e.ndim == 2:
-                cw = cw[:, None]
-            return (e < ta) & ((e < tf) | ((e == tf) & cw))
 
         # -- shared pick machinery ------------------------------------
         def cfs_pick_start(st, pickm, t_c, ctx_ref):
@@ -419,7 +640,7 @@ def make_cell_kernel(n_cores: int, n_slots: int):
         def cycle_ff(st, ta, tf, fcid, rot):
             cur, rqn = st["cur"], st["rqn"]
             act0 = (~is_fifo) & (cur >= 0) & (rqn + 1 <= KC) & \
-                bb(st["end"], ta, tf, fcid)
+                before_barrier(st["end"], ta, tf, fcid)
             jj = jnp.arange(KC, dtype=jnp.int32)[None, :]
             idx = jnp.concatenate([cur[:, None], rot[:, :KC - 1]], axis=1)
             valid = act0[:, None] & (jj < (rqn + 1)[:, None])
@@ -462,7 +683,7 @@ def make_cell_kernel(n_cores: int, n_slots: int):
                 qmax = jnp.max(jnp.where(valid & ~onem, cy["vr"],
                                          _VR_BOT), axis=1)
                 fire = cy["alive"] & (r2 > _EPS) & (v2 >= qmax) & \
-                    bb(e, ta, tf, fcid)
+                    before_barrier(e, ta, tf, fcid)
                 fm = fire[:, None] & onem
                 rem_u = jnp.where(fm, r2[:, None], cy["rem"])
                 vr_u = jnp.where(fm, v2[:, None], cy["vr"])
@@ -535,179 +756,7 @@ def make_cell_kernel(n_cores: int, n_slots: int):
                       dtype=jnp.int32),
             ), did
 
-        # -- step 2: windowed rotation retirement ---------------------
-        # PR 4's `_window_fast_forward` twin: evaluate ONE rotation of
-        # a core's runqueue (up to W chunks) at once. Chunk 0 is the
-        # in-flight chunk; chunk i >= 1 pops rotation[i - 1]. All
-        # masks are vector predicates over the chunk axis; only the
-        # end-time chain is sequential (explicit lax.scan left fold).
-        # Completions retire inline; the integer cumsum of completion
-        # flags and the cummax of push keys are the ONLY associative
-        # scans (both exact under reassociation).
-        def window_ff(st, elig, ta, tf, fcid, rot):
-            k1 = st["rqn"]
-            winm = elig & (k1 >= 1)
-            cur = st["cur"]
-            ii = jnp.arange(W + 1, dtype=jnp.int32)[None, :]
-            u = jnp.concatenate([jnp.where(winm, cur, N)[:, None],
-                                 rot[:, :W]], axis=1)       # [C, W+1]
-            uvalid = winm[:, None] & (ii <= k1[:, None])
-            u = jnp.where(uvalid, u, N)
-            su = jnp.minimum(u, N - 1)
-            rem0 = st["rem"][su]
-            vr0 = st["vr"][su]
-            fr0 = st["fr"][su]
-            s = cfs_slice_ms(k1, LAT, GRAN, _max=jnp.maximum)
-            runs = chunk_run_ms(rem0, s[:, None], _min=jnp.minimum,
-                                _max=jnp.maximum)
-            runs = jnp.where(ii == 0, st["clen"][:, None], runs)
-            comp = chunk_completes(rem0, runs)
-            cum = jnp.cumsum(comp.astype(jnp.int32), axis=1)
-            cumx = jnp.concatenate(
-                [jnp.zeros((C, 1), jnp.int32), cum[:, :-1]], axis=1)
-            # slice at chunk i's pick: queue holds k1 - (completions
-            # among chunks < i) entries after the pop.
-            s_i = cfs_slice_ms(k1[:, None] - cumx, LAT, GRAN,
-                               _max=jnp.maximum)
-            slice_ok = (s_i == s[:, None]) | (ii == 0)
-            pushed = vr_add(vr0, jnp.where(ii == 0, st["cslb"][:, None],
-                                           slice_bits(k1)[:, None]))
-            # Stability: a non-completing pushback must land at the
-            # tail — at/after the deepest original key and every
-            # earlier in-window push (exact cummax).
-            pkey = jnp.where(comp, _VR_BOT, pushed)
-            # Deepest original key: rotation is sorted, so the last
-            # queue entry (possibly beyond the window) carries it.
-            tail_tid = jnp.take_along_axis(
-                rot, jnp.maximum(k1 - 1, 0)[:, None], 1)[:, 0]
-            tail0 = st["vr"][tail_tid]
-            prior = jnp.concatenate(
-                [tail0[:, None],
-                 jnp.maximum(tail0[:, None],
-                             lax.cummax(pkey, axis=1)[:, :-1])], axis=1)
-            stab = pushed >= prior
-
-            def estep(e, run_col):
-                e2 = chunk_end_ms(e, CTX, run_col)
-                return e2, e2
-
-            _, etail = lax.scan(estep, st["end"], runs[:, 1:].T,
-                                unroll=8)
-            E = jnp.concatenate([st["end"][:, None], etail.T], axis=1)
-            ok = uvalid & (ii < k1[:, None]) & (ii < W) & \
-                bb(E, ta, tf, fcid) & slice_ok & (comp | stab)
-            c = jnp.argmax(~ok, axis=1).astype(jnp.int32)
-            did = winm & (c >= 1)
-
-            cm = c[:, None]
-            Ec1 = jnp.take_along_axis(E, jnp.maximum(cm - 1, 0), 1)[:, 0]
-            cumc = jnp.take_along_axis(cumx, cm, 1)[:, 0]
-            s_c = jnp.take_along_axis(s_i, cm, 1)[:, 0]
-            rem_c = jnp.take_along_axis(rem0, cm, 1)[:, 0]
-            run_c = chunk_run_ms(rem_c, s_c, _min=jnp.minimum,
-                                 _max=jnp.maximum)
-            end_c = chunk_end_ms(Ec1, CTX, run_c)
-            u_c = jnp.take_along_axis(u, cm, 1)[:, 0]
-            u_cp = jnp.take_along_axis(u, jnp.maximum(cm - 1, 0),
-                                       1)[:, 0]
-            vr0_c = jnp.take_along_axis(vr0, cm, 1)[:, 0]
-
-            R = winm[:, None] & (ii < cm)          # retired chunks
-            Rc = R & comp
-            Rp = R & ~comp
-            # picks: chunks 1..c start at the previous chunk's end;
-            # rotation members are pairwise distinct and distinct from
-            # the chunk-0 task, so every pick charges a ctx switch.
-            P = winm[:, None] & (ii >= 1) & (ii <= cm)
-            Pf = P & jnp.isnan(fr0)                # first runs
-            Eprev = jnp.concatenate([jnp.zeros((C, 1)), E[:, :-1]],
-                                    axis=1)
-            pushseq = st["seqc"][:, None] + (ii - cumx)
-            rem_w = jnp.where(comp, 0.0, rem0 - runs)
-            qc = jnp.broadcast_to(cids[:, None], (C, W + 1))
-
-            if commit == "dense":
-                # Every valid entry of `u` is a distinct task (running
-                # on one core or queued on one), so the commit is a
-                # permutation write: each task reads its one update out
-                # of the window, selected by ownership and reduced over
-                # (core, chunk) against a neutral below every update.
-                tids = jnp.arange(N, dtype=jnp.int32)
-                own = u[:, :, None] == tids            # [C, W+1, N]
-
-                def hit(mask):
-                    return jnp.any(own & mask[:, :, None], axis=(0, 1))
-
-                def read(mask, v):
-                    low = (-_INF if jnp.issubdtype(v.dtype, jnp.floating)
-                           else jnp.iinfo(v.dtype).min)
-                    return jnp.max(jnp.where(own & mask[:, :, None],
-                                             v[:, :, None], low),
-                                   axis=(0, 1))
-
-                hR, hRc, hRp, hP, hPf = map(hit, (R, Rc, Rp, P, Pf))
-                running = jnp.any(did[:, None] & (u_c[:, None] == tids),
-                                  axis=0)
-                new = dict(
-                    rem=jnp.where(hR, read(R, rem_w), st["rem"]),
-                    cpu=jnp.where(hR, st["cpu"] + read(R, runs),
-                                  st["cpu"]),
-                    comp=jnp.where(hRc, read(Rc, E), st["comp"]),
-                    vr=jnp.where(hRp, read(Rp, pushed), st["vr"]),
-                    npre=jnp.where(hRp, st["npre"] + 1, st["npre"]),
-                    seq=jnp.where(hRp, read(Rp, pushseq), st["seq"]),
-                    qcore=jnp.where(hRp, read(Rp, qc), st["qcore"]),
-                    fr=jnp.where(hPf, read(Pf, Eprev), st["fr"]),
-                    nctx=jnp.where(hP, st["nctx"] + 1, st["nctx"]),
-                    stat=jnp.where(running, 3, jnp.where(
-                        hRc, 4, jnp.where(hRp, 2, st["stat"]))),
-                )
-            else:
-                tR = jnp.where(R, u, N).reshape(-1)
-                tRc = jnp.where(Rc, u, N).reshape(-1)
-                tRp = jnp.where(Rp, u, N).reshape(-1)
-                tfr = jnp.where(Pf, u, N).reshape(-1)
-                tP = jnp.where(P, u, N).reshape(-1)
-                new = dict(
-                    rem=st["rem"].at[tR].set(rem_w.reshape(-1),
-                                             mode="drop"),
-                    cpu=st["cpu"].at[tR].add(runs.reshape(-1),
-                                             mode="drop"),
-                    comp=st["comp"].at[tRc].set(E.reshape(-1),
-                                                mode="drop"),
-                    vr=st["vr"].at[tRp].set(pushed.reshape(-1),
-                                            mode="drop"),
-                    npre=st["npre"].at[tRp].add(1, mode="drop"),
-                    seq=st["seq"].at[tRp].set(pushseq.reshape(-1),
-                                              mode="drop"),
-                    qcore=st["qcore"].at[tRp].set(qc.reshape(-1),
-                                                  mode="drop"),
-                    fr=st["fr"].at[tfr].set(Eprev.reshape(-1),
-                                            mode="drop"),
-                    nctx=st["nctx"].at[tP].add(1, mode="drop"),
-                    stat=st["stat"].at[tRp].set(2, mode="drop")
-                        .at[tRc].set(4, mode="drop")
-                        .at[jnp.where(did, u_c, N)].set(3, mode="drop"),
-                )
-
-            st2 = dict(
-                st,
-                **new,
-                cur=jnp.where(did, u_c, st["cur"]),
-                last=jnp.where(did, u_cp, st["last"]),
-                end=jnp.where(did, end_c, st["end"]),
-                clen=jnp.where(did, run_c, st["clen"]),
-                cslb=jnp.where(did, slice_bits(k1 - cumc), st["cslb"]),
-                # pops ratchet min_vruntime through nondecreasing keys:
-                # the iterated max equals one max against the last pop.
-                minvr=jnp.where(did,
-                                jnp.maximum(st["minvr"], vr0_c),
-                                st["minvr"]),
-                seqc=st["seqc"] + jnp.where(did, c - cumc, 0),
-                rqn=jnp.where(did, k1 - cumc, st["rqn"]),
-                ev=st["ev"] + jnp.sum(jnp.where(did, c, 0), dtype=jnp.int32),
-            )
-            return st2, did
+        # -- step 2: the window fast-forward, `window_engine` ---------
 
         # -- step 3: generic one-event CFS advance --------------------
         def cfs_advance(st, elig):
@@ -883,7 +932,7 @@ def make_cell_kernel(n_cores: int, n_slots: int):
             ta = t_arr(st)
             tf, fcid, anyf = fifo_candidate(st)
             elig = (~is_fifo) & (st["cur"] >= 0) & \
-                bb(st["end"], ta, tf, fcid)
+                before_barrier(st["end"], ta, tf, fcid)
             any_cfs = jnp.any(elig)
             do_f = anyf & ~any_cfs & (tf < ta)
             do_a = ~any_cfs & ~do_f & (st["ptr"] < n_tasks)
@@ -910,7 +959,7 @@ def make_cell_kernel(n_cores: int, n_slots: int):
 
         def body(st):
             evl, fires, rqmax = st["evl"], st["fires"], st["rqmax"]
-            rdeep = st["rdeep"]
+            rdeep, wtrips = st["rdeep"], st["wtrips"]
             ev0 = st["ev"]
             st = dict(st, it=st["it"] + 1)
             ta = t_arr(st)
@@ -939,14 +988,24 @@ def make_cell_kernel(n_cores: int, n_slots: int):
 
             tf, fcid, anyf = fifo_candidate(st)
             e = st["end"]
-            elig = (~is_fifo) & (st["cur"] >= 0) & bb(e, ta, tf, fcid)
+            elig = (~is_fifo) & (st["cur"] >= 0) & \
+                before_barrier(e, ta, tf, fcid)
             any_cfs = jnp.any(elig)
             do_f = anyf & ~any_cfs & (tf < ta)
             do_a = ~any_cfs & ~do_f & (st["ptr"] < n_tasks)
 
             with jax.named_scope(window_s):
-                st_w, handled = window_ff(st, elig & ~cdid, ta, tf, fcid,
-                                          rot)
+                # Like the rotation's, the predicate is the batch's: the
+                # window runs on the trips where some core of some cell
+                # retires its in-flight chunk by it, and elsewhere would
+                # return its input unchanged.
+                welig = elig & ~cdid
+                wrun = lax.pmax(jnp.any(window_head(
+                    st, welig, ta, tf, fcid, rot)).astype(jnp.int32), _CELLS)
+                st_w, handled = lax.cond(
+                    wrun > 0, window_ff,
+                    lambda st, *_: (st, jnp.zeros(C, jnp.bool_)),
+                    st, welig, ta, tf, fcid, rot)
             with jax.named_scope(advance_s):
                 st_cfs = cfs_advance(st_w, elig & ~handled)
                 st_fifo = fifo_advance(st, fcid, tf)
@@ -976,7 +1035,8 @@ def make_cell_kernel(n_cores: int, n_slots: int):
                 fires=fires + jnp.stack(
                     [jnp.sum(cdid, dtype=jnp.int32), fire_w]),
                 rqmax=jnp.maximum(rqmax, jnp.max(stn["rqn"])),
-                rdeep=rdeep + (depth > KC - 1).astype(jnp.int32))
+                rdeep=rdeep + (depth > KC - 1).astype(jnp.int32),
+                wtrips=wtrips + wrun)
 
         out = lax.while_loop(cond, body, st)
         live = jnp.arange(N) < n_tasks
@@ -988,7 +1048,8 @@ def make_cell_kernel(n_cores: int, n_slots: int):
                     ok=ok, n_iters=out["it"], n_events=out["ev"],
                     events_by_layer=out["evl"], fires_by_layer=out["fires"],
                     rq_depth_max=out["rqmax"],
-                    rotation_deep_trips=out["rdeep"])
+                    rotation_deep_trips=out["rdeep"],
+                    window_trips=out["wtrips"])
 
     return kernel
 
@@ -1047,7 +1108,8 @@ def run_grid(arrival, service, n_tasks, n_fifo, limit, *, n_cores: int):
 
     Returns a dict of [B, N] observable arrays (see ``make_cell_kernel``)
     and the per-cell counters ``ok``, ``n_iters``, ``n_events``,
-    ``rq_depth_max``, ``rotation_deep_trips`` [B], ``events_by_layer``
+    ``rq_depth_max``, ``rotation_deep_trips``, ``window_trips`` [B],
+    ``events_by_layer``
     [B, 4] (``spans.EVENT_LAYERS`` order) and ``fires_by_layer`` [B, 2]
     (``spans.FIRE_LAYERS`` order), as NumPy, computed under x64 on
     whatever backend JAX selected.

@@ -16,12 +16,16 @@ The kernel also counts, per cell, the events each retirement layer
 retires (``events_by_layer``, in the order of :data:`EVENT_LAYERS`), the
 (trip, core) firings of each multi-event layer (``fires_by_layer``, in
 the order of :data:`FIRE_LAYERS`), the deepest CFS runqueue at the
-end of a trip (``rq_depth_max``) and the trips that began with a CFS
-runqueue deeper than ``kernels._CYCLE_K - 1`` (``rotation_deep_trips``).
-The last counts where the rotation's pop prefix does not serve: a batch
-runs the full sort (``kernels.rotation_sorted``) on a trip if and only
-if some live cell of the batch is deep at its start, and a cell's count
-is its own, whatever batch it runs in.
+end of a trip (``rq_depth_max``), the trips that began with a CFS
+runqueue deeper than ``kernels._CYCLE_K - 1`` (``rotation_deep_trips``)
+and the trips on which its batch ran the window fast-forward
+(``window_trips``). ``rotation_deep_trips`` counts where the rotation's
+pop prefix does not serve: a batch runs the full sort
+(``kernels.rotation_sorted``) on a trip if and only if some live cell
+of the batch is deep at its start, and a cell's count is its own,
+whatever batch it runs in. A batch runs the window on a trip if and
+only if it retires a chunk on some core of some live cell, so
+``window_trips``, unlike the others, is the batch's.
 
 Outside a profiler session a span costs one small object.
 """

@@ -312,11 +312,15 @@ def test_battery_counters(commit_forms, deep):
 @pytest.mark.parametrize("deep", [False, True], ids=["w64", "w256"])
 def test_counters_alone_match_the_batch(commit_forms, deep):
     """A cell's counters (and observables) are its own: run alone, at
-    B = 1, it reads what it read inside the battery's batch."""
+    B = 1, it reads what it read inside the battery's batch. The one
+    exception is ``window_trips``, the trips on which the batch ran the
+    window: alone a cell counts only those it fired on itself."""
     from repro.mc.engine import run_scenarios
     for sc, r in zip(commit_battery(deep), commit_forms["dense"][deep]):
         alone, = run_scenarios([sc])
-        assert alone.mc_stats == r.mc_stats
+        mine, theirs = dict(alone.mc_stats), dict(r.mc_stats)
+        assert mine.pop("window_trips") <= theirs.pop("window_trips")
+        assert mine == theirs
         assert digest(alone) == digest(r)
 
 

@@ -138,6 +138,47 @@ def test_mixed_batch_sorts_for_its_deep_cell():
     assert alone.mc_stats == deep
 
 
+def test_window_trips_count_the_batchs_window(batteries):
+    """A batch runs the window on a trip where some core of some cell
+    retires a chunk by it: each cell counts those trips while it is
+    live, at least one for each trip it fires on, at most its trips,
+    and never all of them: every battery skips the window on some
+    trips. The paper-host cut's cells skip it on most; a fifo cell,
+    which never fires, counts the trips its batch's cfs cell fires on."""
+    for battery, results in batteries.items():
+        n_cores = 50 if battery == "paper" else 2
+        for r in results:
+            st = r.mc_stats
+            fired = st["fires_by_layer"]["window_ff"]
+            assert 0 <= st["window_trips"] < st["iters"]
+            assert fired <= n_cores * st["window_trips"]
+            assert (fired > 0) <= (st["window_trips"] > 0)
+    for r in batteries["paper"]:
+        assert 2 * r.mc_stats["window_trips"] < r.mc_stats["iters"]
+    fifo, cfs = (r.mc_stats for r in batteries["w64"][:2])
+    assert fifo["fires_by_layer"]["window_ff"] == 0
+    assert 0 < fifo["window_trips"] <= cfs["window_trips"]
+
+
+def test_mixed_batch_counts_window_trips_of_the_batch():
+    """Each cell of the mixed batch reads alone every counter it reads
+    in the batch but ``window_trips``, which counts at least the trips
+    it runs the window on alone, where only it can fire, and at most
+    its trips."""
+    from repro.mc.engine import run_scenarios
+    batch = run_scenarios(_mixed_batch())
+    for sc, r in zip(_mixed_batch(), batch):
+        alone, = run_scenarios([sc])
+        mine, theirs = dict(alone.mc_stats), dict(r.mc_stats)
+        own, got = mine.pop("window_trips"), theirs.pop("window_trips")
+        assert mine == theirs
+        assert own <= got <= r.mc_stats["iters"]
+        assert (alone.mc_stats["fires_by_layer"]["window_ff"] > 0) \
+            <= (own > 0)
+        assert _digest(alone) == _digest(r)
+    assert batch[0].mc_stats["fires_by_layer"]["window_ff"] > 0
+
+
 def _computations(hlo_text):
     """``{name: [instruction lines]}`` of an HLO module's text."""
     comps, name = {}, None
@@ -165,11 +206,9 @@ def _reach(comps, root):
     return seen
 
 
-def test_rotation_sorts_only_inside_the_deep_branch():
-    """The compiled program: the rotation holds one conditional, the
-    sorts sit only in its second branch (the deep one: ``lax.cond``
-    lowers its true branch last) and nothing of the first branch
-    sorts."""
+@pytest.fixture(scope="module")
+def program():
+    """The computations of the compiled (4, 64) program at B = 2."""
     import jax
     import jax.numpy as jnp
     from repro.mc import kernels
@@ -179,14 +218,44 @@ def test_rotation_sorts_only_inside_the_deep_branch():
         text = kernels.grid_kernel(4, 64).lower(
             f64, f64, i32, i32, jax.ShapeDtypeStruct((2,), jnp.float64)
         ).compile().as_text()
-    comps = _computations(text)
+    return _computations(text)
+
+
+def _branches(comps, scope):
+    """The (false, true) branch computations of the one conditional
+    under ``scope``; ``lax.cond`` lowers its true branch last."""
+    cond, = [line for lines in comps.values() for line in lines
+             if re.search(r"\bconditional\(", line)
+             and f"/{scope}/" in line]
+    return re.search(r"branch_computations=\{%([\w.\-]+), "
+                     r"%([\w.\-]+)\}", cond).groups()
+
+
+def test_rotation_sorts_only_inside_the_deep_branch(program):
+    """The compiled program: the rotation holds one conditional, the
+    sorts sit only in its second branch (the deep one) and nothing of
+    the first branch sorts."""
+    comps = program
     where = {c: line for c, lines in comps.items() for line in lines
              if re.search(r"\bsort\(", line)}
     assert where and all("/mc_rotation/" in line for line in where.values())
-    cond, = [line for lines in comps.values() for line in lines
-             if re.search(r"\bconditional\(", line)]
-    assert "/mc_rotation/" in cond
-    shallow, deep = re.search(r"branch_computations=\{%([\w.\-]+), "
-                              r"%([\w.\-]+)\}", cond).groups()
+    shallow, deep = _branches(comps, "mc_rotation")
     assert set(where) <= _reach(comps, deep)
     assert not set(where) & _reach(comps, shallow)
+
+
+def test_window_runs_only_inside_its_branch(program):
+    """The compiled program: the window's end-time scan (a while loop
+    under ``mc_window_ff``) and its dense select (every op over the
+    ownership mask [B, C, W + 1, N] = [2, 4, 65, 64]) sit only in the
+    second branch of the window's one conditional, and nothing of the
+    first branch holds either."""
+    comps = program
+    scan = {c for c, lines in comps.items() for line in lines
+            if re.search(r"\bwhile\(", line) and "/mc_window_ff/" in line}
+    select = {c for c, lines in comps.items() for line in lines
+              if "[2,4,65,64]" in line}
+    assert scan and select
+    skip, run = _branches(comps, "mc_window_ff")
+    assert scan | select <= _reach(comps, run)
+    assert not (scan | select) & _reach(comps, skip)
