@@ -13,7 +13,8 @@ Equivalence contract: the engine runs under x64 (entered per call via
 untouched). On the CPU backend it reproduces the scalar engine's
 per-task digests and every cost roll-up bit-for-bit. On the TPU, which
 emulates float64 as double-f32, integer observables are exact and
-floats agree within ``kernels.float_rtol`` (DESIGN.md Sec. 16).
+floats agree within the limits the benchmark's configurations state
+(DESIGN.md Sec. 16).
 
 Heavy imports (jax) are deferred until first use so ``import repro``
 stays light.

@@ -165,12 +165,18 @@ def run_scenarios(scenarios: Sequence["Scenario"],
       cell retires a chunk by it, summed over fleet units. Unlike the
       others this counter is the batch's: alone a cell counts the trips
       on which it fires itself, and in a batch at least those;
+    * ``live_slots``: the task slots live (arrived and not done) at the
+      start of each trip, summed over the trips and fleet units: the
+      part of the trips' ``[C, N]`` work that served live tasks;
     * ``window_len``: W, the chunks a firing scans
-      (``kernels.window_len``), the largest over the units' buckets.
+      (``kernels.window_len``), the largest over the units' buckets;
+    * ``n_slots``: N, the bucket's padded task slots, the largest over
+      the units' buckets.
 
-    ``fires_by_layer``, ``rq_depth_max``, ``rotation_deep_trips`` and
-    ``window_trips`` are left out where the grid function returns only
-    the observables and the events (a stand-in for ``run_grid``).
+    ``fires_by_layer``, ``rq_depth_max``, ``rotation_deep_trips``,
+    ``window_trips`` and ``live_slots`` are left out where the grid
+    function returns only the observables and the events (a stand-in
+    for ``run_grid``).
 
     Under ``jax.profiler`` the call writes host spans (``spans.span``),
     each with the stat ``call``, this process's number of the call:
@@ -242,7 +248,9 @@ def _run(scenarios, prebuilt, call: int) -> list["ScenarioResult"]:
     depth = [0] * len(scenarios)   # deepest runqueue per cell
     deep = [0] * len(scenarios)    # trips that began deep, per cell
     wtrips = [0] * len(scenarios)  # trips the batch ran the window
+    live = [0] * len(scenarios)    # live slots summed over trips
     wlen = [0] * len(scenarios)    # window length per cell
+    nslots = [0] * len(scenarios)  # bucket slots per cell
     counted = True                 # the grid counts firings and depth
     for (C, N), us in groups.items():
         B = len(us)
@@ -288,11 +296,13 @@ def _run(scenarios, prebuilt, call: int) -> list["ScenarioResult"]:
                 events[k] += int(out["n_events"][b])
                 by_layer[k] += out["events_by_layer"][b]
                 wlen[k] = max(wlen[k], window_len(N))
+                nslots[k] = max(nslots[k], N)
                 if counted:
                     fires[k] += out["fires_by_layer"][b]
                     depth[k] = max(depth[k], int(out["rq_depth_max"][b]))
                     deep[k] += int(out["rotation_deep_trips"][b])
                     wtrips[k] += int(out["window_trips"][b])
+                    live[k] += int(out["live_slots"][b])
 
     results: list["ScenarioResult"] = []
     with span("rebuild", call=call):
@@ -307,13 +317,14 @@ def _run(scenarios, prebuilt, call: int) -> list["ScenarioResult"]:
             stats = {"iters": iters[k], "events": events[k],
                      "events_by_layer": dict(zip(
                          EVENT_LAYERS, map(int, by_layer[k]))),
-                     "window_len": wlen[k]}
+                     "window_len": wlen[k], "n_slots": nslots[k]}
             if counted:
                 stats["fires_by_layer"] = dict(zip(
                     FIRE_LAYERS, map(int, fires[k])))
                 stats["rq_depth_max"] = depth[k]
                 stats["rotation_deep_trips"] = deep[k]
                 stats["window_trips"] = wtrips[k]
+                stats["live_slots"] = live[k]
             results.append(ScenarioResult(
                 scenario=sc, raw=raw, meta=dict(meta), mc_stats=stats,
                 backend="jax"))

@@ -17,10 +17,14 @@ retires (``events_by_layer``, in the order of :data:`EVENT_LAYERS`), the
 (trip, core) firings of each multi-event layer (``fires_by_layer``, in
 the order of :data:`FIRE_LAYERS`), the deepest CFS runqueue at the
 end of a trip (``rq_depth_max``), the trips that began with a CFS
-runqueue deeper than ``kernels._CYCLE_K - 1`` (``rotation_deep_trips``)
-and the trips on which its batch ran the window fast-forward
-(``window_trips``). ``rotation_deep_trips`` counts where the rotation's
-pop prefix does not serve: a batch runs the full sort
+runqueue deeper than ``kernels._CYCLE_K - 1`` (``rotation_deep_trips``),
+the trips on which its batch ran the window fast-forward
+(``window_trips``) and, summed over its trips, the task slots live
+(arrived and not done) at a trip's start (``live_slots``), so that
+``live_slots`` over trips x N is the share of a trip's ``[C, N]``
+sorts, masks and selects that serves live tasks.
+``rotation_deep_trips`` counts where the rotation's pop prefix does not
+serve: a batch runs the full sort
 (``kernels.rotation_sorted``) on a trip if and only if some live cell
 of the batch is deep at its start, and a cell's count is its own,
 whatever batch it runs in. A batch runs the window on a trip if and
